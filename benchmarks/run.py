@@ -32,6 +32,8 @@ def main(argv=None) -> int:
                     help="paper-scale sizes (slow); default is scaled-down")
     ap.add_argument("--only", default=None, choices=list(SUITES))
     args = ap.parse_args(argv)
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
 
     failures = []
     all_claims = {}

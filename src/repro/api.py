@@ -254,7 +254,7 @@ class Aligner:
         Execution comes in as ``options=QueryOptions(...)``, whose
         ``plan`` names the pipeline: ``"cpu"`` (NumPy reference path, the
         default), ``"device"`` (the arena stays resident on the
-        accelerator; probe and sweep run as Pallas kernels, block-identical
+        accelerator; probe and sweep run there, block-identical
         to cpu), or ``"auto"`` (device when a real accelerator backs jax,
         else silently cpu).  Stage fields on the options object pin
         individual stages for debugging — e.g.

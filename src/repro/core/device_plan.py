@@ -5,8 +5,9 @@ arena binary search (even with ``probe_backend="pallas"``) re-uploads the
 key arena each launch, the collided window rows are gathered on the host,
 and the grouped sweep is NumPy.  This module keeps the heavy state — the
 fused :class:`~repro.core.frozen.ProbeArena` key/offset/window arrays —
-*resident* on the accelerator and runs the probe binary search and the
-grouped small-group sweep as Pallas kernels, so per batch only
+*resident* on the accelerator and runs the probe binary search (a jitted
+XLA program over the HBM-resident arrays) and the grouped small-group
+sweep (a Pallas kernel) on the device, so per batch only
 
 * up:   the packed probe keys (B*k few-byte words) and the small-group
   gather index grids,
@@ -22,10 +23,13 @@ keyed by the *identity* of its host ``ProbeArena``: a ``SearchIndex`` is
 immutable, and every path that changes the store generation
 (``LiveIndex.compact``/``promote_sealed``) swaps in a NEW ``SearchIndex``,
 so the upload happens at most once per store generation and invalidation
-is automatic.  The mutable live delta level never comes through here — it
-keeps the host dict probe (``repro.core.query.batch_probe`` routes
-non-frozen levels to the per-coordinate loop), which is what keeps live
-serving correct between compactions.
+is automatic.  An arena the device probe cannot address (a CSR extent past
+int32) raises :class:`DeviceArenaError`: the device plan never quietly
+serves a batch on the host.  The mutable live delta level never comes
+through here — it keeps the host dict probe
+(``repro.core.query.batch_probe`` routes non-frozen levels to the
+per-coordinate loop), which is what keeps live serving correct between
+compactions.
 
 Bit parity
 ----------
@@ -38,12 +42,15 @@ bit-identical to ``plan="cpu"`` — gated in ``tests/test_device_plan.py``.
 ``transfer_stats()`` exposes logical host<->device byte counters (what
 crosses the bus on a real accelerator; in interpret mode the same arrays
 flow, uncounted copies aside) for the residency tests and the roofline
-benchmark's fused-pipeline row.
+benchmark's fused-pipeline row, plus three work counters: ``batches``
+(probes of a resident arena, one per batch and frozen level),
+``sweep_launches`` (device sweep launches) and ``host_large_groups``
+(groups of more than ``_SMALL_GROUP_MAX`` windows, which the device plan
+sweeps on the host by design).
 """
 
 from __future__ import annotations
 
-import functools
 import time
 from dataclasses import dataclass
 
@@ -51,16 +58,19 @@ import numpy as np
 
 from .frozen import MODE_PACKED, PACK_SHIFT, _concat_ranges
 
-__all__ = ["DeviceArena", "device_arena", "resident_probe",
-           "fused_batch_query", "transfer_stats", "reset_transfer_stats"]
+__all__ = ["DeviceArena", "DeviceArenaError", "device_arena",
+           "resident_probe", "fused_batch_query", "transfer_stats",
+           "reset_transfer_stats"]
 
 _I32_MAX = np.iinfo(np.int32).max
 
 # logical host<->device transfer accounting (bytes that cross the bus on
 # a real accelerator).  arena_* count the once-per-generation residency
-# upload; h2d/d2h count the per-batch steady-state traffic.
+# upload; h2d/d2h count the per-batch steady-state traffic; the rest
+# count device probes, device sweep launches and host-swept large groups.
 _STATS = {"arena_uploads": 0, "arena_bytes": 0,
-          "h2d_bytes": 0, "d2h_bytes": 0, "batches": 0}
+          "h2d_bytes": 0, "d2h_bytes": 0, "batches": 0,
+          "sweep_launches": 0, "host_large_groups": 0}
 
 
 def transfer_stats() -> dict:
@@ -73,9 +83,15 @@ def reset_transfer_stats() -> None:
         _STATS[key] = 0
 
 
-def _interpret() -> bool:
-    import jax
-    return jax.default_backend() != "tpu"
+def add_counts(**counts: int) -> None:
+    """Add to the module's counters (the device sweep's callers report
+    ``sweep_launches`` and ``host_large_groups`` through here)."""
+    for key, n in counts.items():
+        _STATS[key] += int(n)
+
+
+class DeviceArenaError(RuntimeError):
+    """A store's arena cannot go resident on the device."""
 
 
 @dataclass
@@ -83,11 +99,12 @@ class DeviceArena:
     """One store generation's ProbeArena, resident on the accelerator.
 
     Keys are split into u32 (hi, lo) halves plus the coordinate tag word
-    (the probe kernel's comparison format); offsets are narrowed to int32
-    (guarded at build — an arena too large falls back to the host probe);
+    (the probe's comparison format); offsets are narrowed to int32
+    (guarded at build — an arena too large raises ``DeviceArenaError``);
     ``win_rect`` holds only the (a, b, c, d) rectangle columns, because
     the text-id column is read host-side (an mmap column read) for
-    grouping and never needs the bus.
+    grouping and never needs the bus.  An empty arena is resident with
+    ``n == 0`` and answers every probe with a miss.
     """
 
     mode: str
@@ -100,12 +117,16 @@ class DeviceArena:
     nbytes: int
 
 
-def _build_device_arena(arena) -> DeviceArena | None:
-    """Upload one ProbeArena; ``None`` when it cannot go resident (empty,
-    or its CSR extent overflows the kernel's int32 offsets)."""
+def _build_device_arena(arena) -> DeviceArena:
+    """Upload one ProbeArena.  Raises :class:`DeviceArenaError` when its
+    CSR extent overflows the device probe's int32 offsets."""
     n = len(arena.keys)
-    if n == 0 or int(arena.offsets[-1]) > _I32_MAX:
-        return None
+    nwin = int(arena.offsets[-1])
+    if nwin > _I32_MAX:
+        raise DeviceArenaError(
+            f"the arena holds {nwin} windows, past the device probe's int32 "
+            f"offsets (at most {_I32_MAX}); serve this store with "
+            'plan="cpu" or split it into shards')
     import jax.numpy as jnp
 
     from ..kernels.probe_arena import _split_u64
@@ -127,19 +148,20 @@ def _build_device_arena(arena) -> DeviceArena | None:
     return dev
 
 
-def device_arena(index) -> DeviceArena | None:
+def device_arena(index) -> DeviceArena:
     """The index's resident arena, uploading on first use and caching on
     the index instance (``SearchIndex._device_arena``).  The cache is
     keyed by the host ``ProbeArena``'s identity, so a promotion/compaction
     (which swaps in a new ``SearchIndex`` and so a new arena) re-uploads
-    exactly once and stale residency can never serve a new generation."""
+    exactly once and stale residency can never serve a new generation.
+    Raises :class:`DeviceArenaError` when the arena cannot go resident."""
     arena = index.arena()
     cached = getattr(index, "_device_arena", None)
     if cached is not None and cached[0] is arena:
         return cached[1]
     dev = _build_device_arena(arena)
     try:
-        index._device_arena = (arena, dev)   # also caches the None fallback
+        index._device_arena = (arena, dev)
     except (AttributeError, TypeError):
         pass                                 # slotted/frozen duck: no cache
     return dev
@@ -158,11 +180,10 @@ def _probe_jit_factory():
 
     from ..kernels.probe_arena import _arena_search
 
-    @functools.partial(jax.jit, static_argnames=("interpret",))
-    def probe(khi, klo, ktag, offsets, qhi, qlo, qtag, valid, *, interpret):
+    @jax.jit
+    def probe(khi, klo, ktag, offsets, qhi, qlo, qtag, valid):
         n = khi.shape[0]
-        pos = _arena_search(khi, klo, ktag, qhi, qlo, qtag,
-                            interpret=interpret)
+        pos = _arena_search(khi, klo, ktag, qhi, qlo, qtag)
         safe = jnp.minimum(pos, n - 1)
         # generic (hi, lo, tag) equality covers both arena modes: packed
         # arenas carry all-zero tags (and all-zero probe tags), coord
@@ -204,15 +225,16 @@ def _device_probe(da: DeviceArena, pkeys, coords, valid
     if _PROBE_JIT is None:
         _PROBE_JIT = _probe_jit_factory()
     import jax.numpy as jnp
-    if len(pkeys) == 0:
-        z = np.zeros(0, np.int64)
+    _STATS["batches"] += 1
+    if len(pkeys) == 0 or da.n == 0:
+        z = np.zeros(len(pkeys), np.int64)
         return z, z
     qhi, qlo, qtag = _encode_queries(da.mode, pkeys, coords, valid)
     valid = np.ascontiguousarray(valid, bool)
     starts, ends = _PROBE_JIT(
         da.khi, da.klo, da.ktag, da.offsets,
         jnp.asarray(qhi), jnp.asarray(qlo), jnp.asarray(qtag),
-        jnp.asarray(valid), interpret=_interpret())
+        jnp.asarray(valid))
     _STATS["h2d_bytes"] += (qhi.nbytes + qlo.nbytes + qtag.nbytes +
                             valid.nbytes)
     starts = np.asarray(starts, np.int64)
@@ -224,12 +246,8 @@ def _device_probe(da: DeviceArena, pkeys, coords, valid
 def resident_probe(index, pkeys, coords, valid
                    ) -> tuple[np.ndarray, np.ndarray]:
     """``ProbeArena.probe``-identical (starts, ends), probing the resident
-    device arena.  Falls back to the host searchsorted when the arena
-    cannot go resident."""
-    da = device_arena(index)
-    if da is None:
-        return index.arena().probe(pkeys, coords, valid, backend="numpy")
-    return _device_probe(da, pkeys, coords, valid)
+    device arena."""
+    return _device_probe(device_arena(index), pkeys, coords, valid)
 
 
 # --------------------------------------------------------------------------
@@ -252,12 +270,6 @@ def fused_batch_query(index, sketches, B: int, m: int, *,
     k = arena.k
     pkeys, coords, valid = arena.encode_batch(sketches)
     da = device_arena(index)
-    _STATS["batches"] += 1
-    if da is None:
-        # arena too large for the kernel's i32 offsets: whole batch on host
-        from .query import _gather_arena, _sweep_gathered
-        return _sweep_gathered(_gather_arena(index, sketches, "numpy"),
-                               B, m, "grouped")
     starts, ends = _device_probe(da, pkeys, coords, valid)
     counts = ends - starts
     row_ids = _concat_ranges(starts, counts)
@@ -278,7 +290,6 @@ def fused_batch_query(index, sketches, B: int, m: int, *,
         qid_s, tid_s, row_s = qid_all[order], tid_all[order], row_ids[order]
         keep = distinct >= m
         sizes = g_ends - g_starts
-        interpret = _interpret()
 
         small_results: dict[int, list] = {}
         sm_ids = np.flatnonzero(keep & (sizes <= _SMALL_GROUP_MAX))
@@ -297,8 +308,8 @@ def fused_batch_query(index, sketches, B: int, m: int, *,
             # device-side row gather from the resident rectangle columns:
             # only the (G, S) index grid goes up, never the window rows
             rects = jnp.take(da.win_rect, jnp.asarray(idx), axis=0)
-            hot, xs, ys = sweep_grid(rects, jnp.asarray(sz32), m=m,
-                                     interpret=interpret)
+            hot, xs, ys = sweep_grid(rects, jnp.asarray(sz32), m=m)
+            _STATS["sweep_launches"] += 1
             _STATS["h2d_bytes"] += idx.nbytes + sz32.nbytes
             NX = int(xs.shape[1])
             # bool-cast on device: the grid crosses at 1 byte per cell
@@ -309,7 +320,9 @@ def fused_batch_query(index, sketches, B: int, m: int, *,
             for g, blocks in zip(ids, _extract_runs(hot_np, xs_np, ys_np)):
                 small_results[int(g)] = blocks
 
-        for g in np.flatnonzero(keep):
+        kept = np.flatnonzero(keep)
+        _STATS["host_large_groups"] += len(kept) - len(small_results)
+        for g in kept:
             g = int(g)
             lo = g_starts[g]
             if g in small_results:

@@ -44,7 +44,7 @@ stage).  Two re-keying schemes, chosen at build time:
   because the k hash functions are independent).
 
 Both schemes resolve to the same slot the lexicographic binary search in
-the Pallas kernel (``repro.kernels.probe_arena``) finds, so the NumPy and
+the device search (``repro.kernels.probe_arena``) finds, so the NumPy and
 device probe backends are bit-for-bit interchangeable.
 """
 
@@ -474,12 +474,13 @@ class ProbeArena:
         return pkeys, coords, valid
 
     def probe(self, pkeys: np.ndarray, coords: np.ndarray,
-              valid: np.ndarray, *, backend: str = "numpy",
-              interpret: bool | None = None
+              valid: np.ndarray, *, backend: str = "numpy"
               ) -> tuple[np.ndarray, np.ndarray]:
         """Vectorized arena lookup -> CSR (starts, ends) int64, one
-        ``searchsorted`` (or one Pallas launch) for the whole batch.
-        Misses get an empty range (start == end == 0)."""
+        ``searchsorted`` for the whole batch (``backend="pallas"``: one
+        device binary search, :mod:`repro.kernels.probe_arena`, uploading
+        the arena per call).  Misses get an empty range (start == end ==
+        0)."""
         n = len(self.keys)
         if n == 0 or len(pkeys) == 0:
             z = np.zeros(len(pkeys), np.int64)
@@ -488,16 +489,14 @@ class ProbeArena:
             q = (coords.astype(np.uint64) << np.uint64(PACK_SHIFT)) | \
                 np.where(valid, pkeys, 0)
             if backend == "pallas":
-                pos = self._pallas_search(q, np.zeros(len(q), np.uint32),
-                                          interpret=interpret)
+                pos = self._device_search(q, np.zeros(len(q), np.uint32))
             else:
                 pos = np.searchsorted(self.keys, q)
             safe = np.minimum(pos, n - 1)
             hit = valid & (pos < n) & (self.keys[safe] == q)
         else:
             if backend == "pallas":
-                pos = self._pallas_search(pkeys, coords.astype(np.uint32),
-                                          interpret=interpret)
+                pos = self._device_search(pkeys, coords.astype(np.uint32))
             else:
                 pos = np.searchsorted(self.keys, pkeys)
                 # advance over the (tiny) duplicate run to the probe's
@@ -516,15 +515,15 @@ class ProbeArena:
         ends = np.where(hit, self.offsets[safe + 1], 0)
         return starts, ends
 
-    def _pallas_search(self, qkeys: np.ndarray, qtags: np.ndarray, *,
-                       interpret: bool | None) -> np.ndarray:
+    def _device_search(self, qkeys: np.ndarray, qtags: np.ndarray
+                       ) -> np.ndarray:
         from ..kernels.probe_arena import arena_search
         if self.mode == MODE_COORD:
             tags = np.ascontiguousarray(self.coords, dtype=np.uint32)
         else:
             tags = np.zeros(len(self.keys), np.uint32)
         return np.asarray(arena_search(
-            np.asarray(self.keys), tags, qkeys, qtags, interpret=interpret),
+            np.asarray(self.keys), tags, qkeys, qtags),
             dtype=np.int64)
 
     # -- introspection ------------------------------------------------------

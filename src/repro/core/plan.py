@@ -10,11 +10,12 @@ accelerator" meant knowing four stage-level spellings.  An
   is the bit-parity oracle every other plan is gated against.
 * ``"device"`` — the device-resident path (:mod:`repro.core.device_plan`):
   the arena stays resident on the accelerator across batches, the probe
-  binary search and the small-group sweep's difference-array run as Pallas
-  kernels, and only final block extents return to host.  Sketching stays
-  on the exact host path by default so the plan is bit-identical to
-  ``"cpu"`` by construction; pin ``sketch_backend="pallas"`` to move the
-  (f32) ICWS sketch onto the device too.
+  binary search (jitted XLA) and the small-group sweep (a Pallas kernel)
+  run on the device, and only final block extents return to host.
+  Sketching stays on the exact host path by default so the plan is
+  bit-identical to ``"cpu"`` by construction; pin
+  ``sketch_backend="pallas"`` to move the (f32) ICWS sketch onto the
+  device too.
 * ``"auto"``   — resolve once per batch: ``"device"`` when a real
   accelerator backs jax, else silently ``"cpu"``.
 
@@ -110,12 +111,11 @@ register_plan("device", defaults={
 def device_preferred() -> bool:
     """Capability check for ``plan="auto"``: is a real accelerator backing
     jax?  Interpret-mode Pallas on CPU is correct but slower than NumPy,
-    so auto only picks the device plan when the hardware pays for it."""
-    try:
-        import jax
-        return jax.default_backend() in ("tpu", "gpu")
-    except Exception:
-        return False
+    so auto only picks the device plan when the hardware pays for it.  A
+    backend that fails to initialise raises here: it is an error to fix,
+    not a reason to serve on the cpu."""
+    import jax
+    return jax.default_backend() in ("tpu", "gpu")
 
 
 def _capable(name: str, capabilities: dict | None) -> bool:

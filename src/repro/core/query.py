@@ -10,7 +10,7 @@ Two execution paths over the same algorithm:
   probes ALL B*k (query, coordinate) pairs against the fused probe arena
   (``repro.core.frozen.ProbeArena``) in ONE ``searchsorted`` + gather
   (``probe_backend="numpy"``; ``"pallas"`` routes the binary search through
-  the device kernel, ``"percoord"`` keeps the legacy per-coordinate probe
+  the device search, ``"percoord"`` keeps the legacy per-coordinate probe
   loop, which is also what mutable dict indexes use), and groups the
   collided windows by (query, text) with one lexsort.  The per-group plane
   sweep goes through a grouped dispatcher: the many tiny groups of Zipf
@@ -298,7 +298,7 @@ def batch_query(index, queries, theta: float, *,
     field picks the pipeline (``"cpu"`` — exact host sketch, one host
     ``searchsorted`` over the fused arena, vectorized grouped sweep;
     ``"device"`` — arena resident on the accelerator, probe binary search
-    and small-group sweep as Pallas kernels, fused so only probe inputs go
+    and small-group sweep on the device, fused so only probe inputs go
     up and final block extents come down; ``"auto"`` — device when a real
     accelerator backs jax, else cpu), resolved ONCE per batch by
     :func:`repro.core.plan.resolve_plan`.  Stage fields on the options
@@ -445,14 +445,20 @@ def _sweep_gathered(gathered, B: int, m: int, sweep: str
             arr[np.repeat(np.arange(G), s_sizes), slot] = rows
             if sweep == "device":
                 from ..kernels.sweep_grid import sweep_small_batch_device
+                from .device_plan import add_counts
                 batched = _extract_runs(
                     *sweep_small_batch_device(arr, s_sizes, m))
+                add_counts(sweep_launches=1)
             else:
                 batched = _sweep_small_batch(arr, s_sizes, m)
             for g, blocks in zip(ids, batched):
                 small_results[int(g)] = blocks
 
-    for g in np.flatnonzero(keep):
+    kept = np.flatnonzero(keep)
+    if sweep == "device":
+        from .device_plan import add_counts
+        add_counts(host_large_groups=len(kept) - len(small_results))
+    for g in kept:
         g = int(g)
         lo = starts[g]
         blocks = small_results[g] if g in small_results else \

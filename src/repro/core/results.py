@@ -196,7 +196,7 @@ class QueryOptions:
 
     plan: which :class:`repro.core.plan.ExecutionPlan` runs the batch —
         ``"cpu"`` (NumPy reference path), ``"device"`` (arena resident on
-        the accelerator, probe + sweep as Pallas kernels) or ``"auto"``
+        the accelerator, probe and sweep run there) or ``"auto"``
         (device when a real accelerator backs jax, else silently cpu).
         Resolved once per batch by ``repro.core.plan.resolve_plan``.
     sketch_backend / probe_backend / sweep / fanout: per-stage *pins*.

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
+
 BS = 128
 _NEG = -1.0e30
 
@@ -59,7 +61,7 @@ def _decode_attn_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_attention_pallas(q, k_cache, v_cache, pos, *,
-                            interpret: bool = True):
+                            interpret: bool | None = None):
     """q (B,H,D); k/v cache (B,S,KV,D); pos scalar i32 -> out (B,H,D)."""
     B, H, D = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
@@ -86,6 +88,6 @@ def decode_attention_pallas(q, k_cache, v_cache, pos, *,
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pos_arr, q, k_cache, v_cache)
     return out

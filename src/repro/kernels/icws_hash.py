@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .interpret import resolve_interpret
+
 BK, BT = 8, 128
 _BIG = 3.0e38  # python literal: pallas kernels cannot capture array constants
 
@@ -43,7 +45,7 @@ def _hash_grid_kernel(r_ref, c_ref, b_ref, w_ref, kint_ref, a_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def icws_hash_grid(r, c, beta, w, *, interpret: bool = True):
+def icws_hash_grid(r, c, beta, w, *, interpret: bool | None = None):
     """r,c,beta (K,T) f32; w (T,) f32 (w<=0 = masked) -> (kint i32, a f32)."""
     K, T = r.shape
     Kp, Tp = -(-K // BK) * BK, -(-T // BT) * BT
@@ -67,9 +69,23 @@ def icws_hash_grid(r, c, beta, w, *, interpret: bool = True):
             jax.ShapeDtypeStruct((Kp, Tp), jnp.int32),
             jax.ShapeDtypeStruct((Kp, Tp), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pad2(r), pad2(c), pad2(beta), wp)
     return kint[:K, :T], a[:K, :T]
+
+
+def _block_argmin(a, kint):
+    """Row-wise (min, first argmin, kint at the argmin) of a (BK, BT)
+    block, each (BK, 1): a min-reduce, then the smallest lane index that
+    attains it (``jnp.argmin``'s tie rule), then ``kint`` picked by an
+    iota mask — Mosaic lowers no gather or variadic argmin reduce."""
+    amin = jnp.min(a, axis=1, keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    loc = jnp.min(jnp.where(a == amin, lane, a.shape[1]), axis=1,
+                  keepdims=True)
+    kmin = jnp.sum(jnp.where(lane == loc, kint.astype(jnp.int32), 0),
+                   axis=1, keepdims=True)
+    return amin, loc, kmin
 
 
 def _sketch_kernel(r_ref, c_ref, b_ref, w_ref,
@@ -91,19 +107,15 @@ def _sketch_kernel(r_ref, c_ref, b_ref, w_ref,
     kint = jnp.floor(lw / r + beta)
     a = jnp.where(valid, c * jnp.exp(-r * (kint - beta) - r), _BIG)
 
-    loc = jnp.argmin(a, axis=1)                       # (BK,)
-    rows = jnp.arange(a.shape[0])
-    amin = a[rows, loc]
-    upd = amin < mina_ref[..., 0]
-    tglob = (j * BT + loc).astype(jnp.int32)
-    mina_ref[..., 0] = jnp.where(upd, amin, mina_ref[..., 0])
-    argt_ref[..., 0] = jnp.where(upd, tglob, argt_ref[..., 0])
-    kint_ref[..., 0] = jnp.where(upd, kint[rows, loc].astype(jnp.int32),
-                                 kint_ref[..., 0])
+    amin, loc, kmin = _block_argmin(a, kint)          # (BK, 1) each
+    upd = amin < mina_ref[...]
+    mina_ref[...] = jnp.where(upd, amin, mina_ref[...])
+    argt_ref[...] = jnp.where(upd, j * BT + loc, argt_ref[...])
+    kint_ref[...] = jnp.where(upd, kmin, kint_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def icws_sketch(r, c, beta, w, *, interpret: bool = True):
+def icws_sketch(r, c, beta, w, *, interpret: bool | None = None):
     """Fused CWS sketch: -> (min_a (K,), argmin_token (K,), k_int (K,))."""
     K, T = r.shape
     Kp, Tp = -(-K // BK) * BK, -(-T // BT) * BT
@@ -129,7 +141,7 @@ def icws_sketch(r, c, beta, w, *, interpret: bool = True):
             jax.ShapeDtypeStruct((Kp, 1), jnp.int32),
             jax.ShapeDtypeStruct((Kp, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pad2(r), pad2(c), pad2(beta), wp)
     return mina[:K, 0], argt[:K, 0], kint[:K, 0]
 
@@ -153,19 +165,15 @@ def _sketch_batch_kernel(r_ref, c_ref, b_ref, w_ref,
     kint = jnp.floor(lw / r + beta)
     a = jnp.where(valid, c * jnp.exp(-r * (kint - beta) - r), _BIG)
 
-    loc = jnp.argmin(a, axis=1)                       # (BK,)
-    rows = jnp.arange(a.shape[0])
-    amin = a[rows, loc]
-    upd = amin < mina_ref[0, :, 0]
-    tglob = (j * BT + loc).astype(jnp.int32)
-    mina_ref[0, :, 0] = jnp.where(upd, amin, mina_ref[0, :, 0])
-    argt_ref[0, :, 0] = jnp.where(upd, tglob, argt_ref[0, :, 0])
-    kint_ref[0, :, 0] = jnp.where(upd, kint[rows, loc].astype(jnp.int32),
-                                  kint_ref[0, :, 0])
+    amin, loc, kmin = _block_argmin(a, kint)          # (BK, 1) each
+    upd = amin < mina_ref[0]
+    mina_ref[0] = jnp.where(upd, amin, mina_ref[0])
+    argt_ref[0] = jnp.where(upd, j * BT + loc, argt_ref[0])
+    kint_ref[0] = jnp.where(upd, kmin, kint_ref[0])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def icws_sketch_batch(r, c, beta, w, *, interpret: bool = True):
+def icws_sketch_batch(r, c, beta, w, *, interpret: bool | None = None):
     """Batched fused CWS sketch, one launch for the whole query batch.
 
     r,c,beta (B,K,T) f32; w (B,T) f32 (w<=0 = padding mask) ->
@@ -196,6 +204,6 @@ def icws_sketch_batch(r, c, beta, w, *, interpret: bool = True):
             jax.ShapeDtypeStruct((B, Kp, 1), jnp.int32),
             jax.ShapeDtypeStruct((B, Kp, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pad3(r), pad3(c), pad3(beta), wp)
     return mina[:, :K, 0], argt[:, :K, 0], kint[:, :K, 0]

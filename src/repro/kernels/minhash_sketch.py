@@ -21,6 +21,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from .common import hash32
+from .interpret import resolve_interpret
 
 BK, BN = 8, 128
 _U32MAX = np.uint32(0xFFFFFFFF)
@@ -44,7 +45,7 @@ def _minhash_kernel(tok_ref, occ_ref, seed_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def minhash_sketch(tokens, occ, seeds, *, interpret: bool = True):
+def minhash_sketch(tokens, occ, seeds, *, interpret: bool | None = None):
     """tokens (B,N) i32 (pad=-1), occ (B,N) i32 (1-based occurrence index),
     seeds (K,) u32 -> sketches (B,K) u32."""
     B, N = tokens.shape
@@ -63,6 +64,6 @@ def minhash_sketch(tokens, occ, seeds, *, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, BK), lambda b, i, j: (b, i)),
         out_shape=jax.ShapeDtypeStruct((B, Kp), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(tok, occ, sd)
     return out[:, :K]

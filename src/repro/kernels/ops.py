@@ -1,14 +1,13 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-`interpret` defaults to True off-TPU (the container is CPU-only; interpret
-mode executes the kernel body exactly, which is what the allclose tests
-validate).  On a real TPU backend pass interpret=False (or rely on the
-default) to run the compiled Mosaic kernels.
+``interpret=None`` (the default) lets :mod:`repro.kernels.interpret`
+decide: compiled Mosaic kernels on a TPU backend, the Pallas interpreter
+elsewhere (it executes the kernel body exactly, which is what the
+allclose tests validate).
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -19,10 +18,6 @@ from .minhash_sketch import minhash_sketch
 from .ref import (decode_attention_ref, icws_sketch_ref,
                   minhash_sketch_ref, selective_scan_ref)
 from .selective_scan import selective_scan_pallas
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def icws_token_params(seed: int, k: int, tokens) -> tuple:
@@ -48,8 +43,7 @@ def cws_sketch(seed: int, k: int, tokens, weights, *,
     r, c, b = icws_token_params(seed, k, tokens)
     w = jnp.asarray(weights, jnp.float32)
     if use_pallas:
-        interp = _default_interpret() if interpret is None else interpret
-        mina, argt, kint = icws_sketch(r, c, b, w, interpret=interp)
+        mina, argt, kint = icws_sketch(r, c, b, w, interpret=interpret)
     else:
         mina, argt, kint = icws_sketch_ref(r, c, b, w)
     toks = jnp.asarray(np.asarray(tokens), jnp.int32)
@@ -80,10 +74,9 @@ def cws_sketch_batch(seed: int, k: int, token_lists, weight_lists, *,
         r[b, :, t:] = c[b, :, t:] = be[b, :, t:] = 1.0
         w[b, :t] = np.asarray(wl, np.float32)
         toks[b, :t] = np.asarray(tl, np.int64)
-    interp = _default_interpret() if interpret is None else interpret
     _mina, argt, kint = icws_sketch_batch(jnp.asarray(r), jnp.asarray(c),
                                           jnp.asarray(be), jnp.asarray(w),
-                                          interpret=interp)
+                                          interpret=interpret)
     argt = np.asarray(argt)
     kint = np.asarray(kint)
     return [[(int(toks[b, argt[b, i]]), int(kint[b, i])) for i in range(k)]
@@ -97,8 +90,7 @@ def multiset_sketch(tokens, occ, seeds, *, use_pallas: bool = True,
     occ = jnp.asarray(occ, jnp.int32)
     seeds = jnp.asarray(seeds, jnp.uint32)
     if use_pallas:
-        interp = _default_interpret() if interpret is None else interpret
-        return minhash_sketch(tokens, occ, seeds, interpret=interp)
+        return minhash_sketch(tokens, occ, seeds, interpret=interpret)
     return minhash_sketch_ref(tokens, occ, seeds)
 
 
@@ -106,17 +98,16 @@ def flash_decode_attention(q, k_cache, v_cache, pos, *,
                            use_pallas: bool = True,
                            interpret: bool | None = None):
     if use_pallas:
-        interp = _default_interpret() if interpret is None else interpret
         return decode_attention_pallas(q, k_cache, v_cache, pos,
-                                       interpret=interp)
+                                       interpret=interpret)
     return decode_attention_ref(q, k_cache, v_cache, pos)
 
 
 def fused_selective_scan(dt, Bc, Cc, x, A, D, *, use_pallas: bool = True,
                          interpret: bool | None = None):
     if use_pallas:
-        interp = _default_interpret() if interpret is None else interpret
-        return selective_scan_pallas(dt, Bc, Cc, x, A, D, interpret=interp)
+        return selective_scan_pallas(dt, Bc, Cc, x, A, D,
+                                     interpret=interpret)
     return selective_scan_ref(dt, Bc, Cc, x, A, D)
 
 

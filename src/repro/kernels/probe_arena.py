@@ -1,6 +1,6 @@
-"""Pallas TPU kernel: device-side probe of the fused CSR arena.
+"""Device-side probe of the fused CSR arena.
 
-One launch binary-searches every probe key of a batch against the arena's
+One call binary-searches every probe key of a batch against the arena's
 sorted key array (``repro.core.frozen.ProbeArena``).  Keys are uint64 on
 the host but TPU VPUs have no 64-bit integer lanes, so arena and probe
 keys are split into (hi, lo) uint32 halves and compared lexicographically;
@@ -8,29 +8,25 @@ the coordinate tag of the arena's "coord" mode rides along as a third
 comparison word (all-zero in "packed" mode, where the coordinate already
 lives in the key's top bits).
 
-Per probe the kernel returns the leftmost arena slot whose
+Per probe the search returns the leftmost arena slot whose
 ``(key, coord) >= (probe key, probe coord)`` — exactly the slot the host
 path's ``np.searchsorted(..., side="left")`` plus duplicate-run advance
-lands on — so hit detection and the CSR offsets/windows gather stay on the
-host and the two probe backends are bit-for-bit identical.
+lands on — so hit detection and the CSR offsets/windows gather agree with
+the host probe bit for bit.
 
-Grid: one step per BP-probe block; the key arena is a single VMEM-resident
-block shared by every step (per-step binary search is O(log n) gathers via
-``jnp.take``).  On a real TPU deployment the arena upload is amortized
-across batches by donation/caching; in interpret mode (CPU CI) the arrays
-pass through as NumPy.
+The search is a jitted XLA program, not a Pallas kernel: a fixed-count
+``fori_loop`` of halvings, each one ``jnp.take`` gather per key word over
+the HBM-resident arrays.  Nothing is staged through VMEM, so the arena
+can be as large as device memory allows (a Pallas kernel would have to
+map the whole arena into VMEM, capping it near a million slots, and
+Mosaic lowers no 1-D gather).
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-
-BP = 128                       # probes per grid step (one VPU lane row)
 
 
 def _lex_less(ahi, alo, atag, bhi, blo, btag):
@@ -39,54 +35,29 @@ def _lex_less(ahi, alo, atag, bhi, blo, btag):
                                           ((alo == blo) & (atag < btag))))
 
 
-def _search_kernel(khi_ref, klo_ref, ktag_ref, qhi_ref, qlo_ref, qtag_ref,
-                   pos_ref, *, n: int, iters: int):
-    khi, klo, ktag = khi_ref[0, :], klo_ref[0, :], ktag_ref[0, :]
-    qhi, qlo, qtag = qhi_ref[0, :], qlo_ref[0, :], qtag_ref[0, :]
-    lo = jnp.zeros(qhi.shape, jnp.int32)
-    hi = jnp.full(qhi.shape, n, jnp.int32)
+@jax.jit
+def _arena_search(khi, klo, ktag, qhi, qlo, qtag):
+    """Leftmost slot with (khi, klo, ktag) >= (qhi, qlo, qtag) per probe,
+    int32 (P,).  The arena (n,) must be non-empty."""
+    n = khi.shape[0]
+    iters = max(1, int(n).bit_length())      # floor(log2 n) + 1 halvings
+    take = lambda a, i: jnp.take(a, i, mode="clip")
 
     def body(_, carry):
         lo, hi = carry
         active = lo < hi
-        mid = (lo + hi) // 2             # < hi <= n, so a safe gather index
+        mid = (lo + hi) // 2             # < hi <= n, so a valid slot
         safe = jnp.minimum(mid, n - 1)
-        less = _lex_less(jnp.take(khi, safe), jnp.take(klo, safe),
-                         jnp.take(ktag, safe), qhi, qlo, qtag)
+        less = _lex_less(take(khi, safe), take(klo, safe), take(ktag, safe),
+                         qhi, qlo, qtag)
         lo = jnp.where(active & less, mid + 1, lo)
         hi = jnp.where(active & ~less, mid, hi)
         return lo, hi
 
+    lo = jnp.zeros(qhi.shape, jnp.int32)
+    hi = jnp.full(qhi.shape, n, jnp.int32)
     lo, _ = jax.lax.fori_loop(0, iters, body, (lo, hi))
-    pos_ref[0, :] = lo
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _arena_search(khi, klo, ktag, qhi, qlo, qtag, *, interpret: bool = True):
-    n = khi.shape[0]
-    P = qhi.shape[0]
-    iters = max(1, int(n).bit_length())      # floor(log2 n) + 1 halvings
-    Np = max(BP, -(-n // BP) * BP)
-    Pp = max(BP, -(-P // BP) * BP)
-    pad_k = lambda a: jnp.pad(a, (0, Np - n))[None, :]
-    pad_q = lambda a: jnp.pad(a, (0, Pp - P))[None, :]
-    pos = pl.pallas_call(
-        functools.partial(_search_kernel, n=n, iters=iters),
-        grid=(Pp // BP,),
-        in_specs=[
-            pl.BlockSpec((1, Np), lambda p: (0, 0)),
-            pl.BlockSpec((1, Np), lambda p: (0, 0)),
-            pl.BlockSpec((1, Np), lambda p: (0, 0)),
-            pl.BlockSpec((1, BP), lambda p: (0, p)),
-            pl.BlockSpec((1, BP), lambda p: (0, p)),
-            pl.BlockSpec((1, BP), lambda p: (0, p)),
-        ],
-        out_specs=pl.BlockSpec((1, BP), lambda p: (0, p)),
-        out_shape=jax.ShapeDtypeStruct((1, Pp), jnp.int32),
-        interpret=interpret,
-    )(pad_k(khi), pad_k(klo), pad_k(ktag), pad_q(qhi), pad_q(qlo),
-      pad_q(qtag))
-    return pos[0, :P]
+    return lo
 
 
 def _split_u64(a: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -95,21 +66,19 @@ def _split_u64(a: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 
 def arena_search(keys: np.ndarray, tags: np.ndarray, qkeys: np.ndarray,
-                 qtags: np.ndarray, *, interpret: bool | None = None
-                 ) -> np.ndarray:
+                 qtags: np.ndarray) -> np.ndarray:
     """Leftmost slot with (key, tag) >= (qkey, qtag) per probe, int32 (P,).
 
     keys (n,) u64 sorted lexicographically with tags (n,) u32 as the tie
-    break; qkeys (P,) u64, qtags (P,) u32.
+    break; qkeys (P,) u64, qtags (P,) u32.  Uploads the arena on every
+    call; ``repro.core.device_plan`` keeps it resident instead.
     """
     if len(keys) == 0:
         return np.zeros(len(qkeys), np.int32)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     khi, klo = _split_u64(keys)
     qhi, qlo = _split_u64(qkeys)
     return np.asarray(_arena_search(
         jnp.asarray(khi), jnp.asarray(klo),
         jnp.asarray(tags, dtype=jnp.uint32),
         jnp.asarray(qhi), jnp.asarray(qlo),
-        jnp.asarray(qtags, dtype=jnp.uint32), interpret=bool(interpret)))
+        jnp.asarray(qtags, dtype=jnp.uint32)))

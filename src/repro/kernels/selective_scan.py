@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .interpret import resolve_interpret
+
 BD, BS = 128, 64
 
 
@@ -34,10 +36,7 @@ def _sel_scan_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, d_ref,
     D = d_ref[...]                           # (1, BD)
 
     def step(t, h):
-        # all-Slice indexers: jax 0.4.x interpret-mode discharge cannot mix
-        # plain-int axes with a traced index (fori_loop t)
-        row = lambda ref: pl.load(
-            ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)))[0, 0]
+        row = lambda ref: ref[0, pl.ds(t, 1), :][0]
         dt_t = row(dt_ref)                   # (BD,)
         x_t = row(x_ref)
         B_t = row(b_ref)                     # (ds,)
@@ -45,8 +44,7 @@ def _sel_scan_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, d_ref,
         a = jnp.exp(dt_t[:, None] * A)       # (BD, ds)
         h = a * h + (dt_t * x_t)[:, None] * B_t[None, :]
         y_t = jnp.sum(h * C_t[None, :], axis=1) + D[0] * x_t
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y_t[None, None, :].astype(y_ref.dtype))
+        y_ref[0, pl.ds(t, 1), :] = y_t[None, :].astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, dt_ref.shape[1], step, h_scr[...])
@@ -58,7 +56,8 @@ def _sel_scan_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, d_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def selective_scan_pallas(dt, Bc, Cc, x, A, D, *, interpret: bool = True):
+def selective_scan_pallas(dt, Bc, Cc, x, A, D, *,
+                          interpret: bool | None = None):
     """dt,x (B,S,di) f32; Bc,Cc (B,S,ds) f32; A (di,ds) f32 (negative);
     D (di,) -> (y (B,S,di) f32, h_final (B,di,ds) f32).
 
@@ -93,6 +92,6 @@ def selective_scan_pallas(dt, Bc, Cc, x, A, D, *, interpret: bool = True):
             jax.ShapeDtypeStruct((B, Dp, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((BD, ds), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(pad3(dt), pads(Bc), pads(Cc), pad3(x), A_p, D_p)
     return y[:, :S, :di], hf[:, :di, :]

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .interpret import resolve_interpret
+
 BG = 8                         # groups per grid step (f32 sublane tile)
 
 _NEG32 = -(1 << 30)            # int32 min // 2 (the host pad sentinel)
@@ -113,16 +115,15 @@ def _sweep_kernel(a_ref, b_ref, c_ref, d_ref, size_ref,
     # zero-width y stripes pass through run extraction unchanged, as on
     # the host
     nz = jnp.concatenate(
-        [xs[:, 1:] > xs[:, :-1],
-         jnp.zeros((xs.shape[0], 1), jnp.bool_)], axis=1)
-    hot = (count >= m) & nz[:, :, None]
-    hot_ref[...] = hot.astype(jnp.int32)
+        [(xs[:, 1:] > xs[:, :-1]).astype(jnp.int32),
+         jnp.zeros((xs.shape[0], 1), jnp.int32)], axis=1)
+    hot_ref[...] = jnp.where(count >= m, nz[:, :, None], 0)
     xs_ref[...] = xs
     ys_ref[...] = ys
 
 
 @functools.partial(jax.jit, static_argnames=("m", "interpret"))
-def sweep_grid(rects, sizes, *, m: int, interpret: bool = True):
+def sweep_grid(rects, sizes, *, m: int, interpret: bool | None = None):
     """Coverage grids for G padded rectangle groups, one Pallas launch.
 
     rects int32 (G, S, 4) — (a, b, c, d) rows, slots past ``sizes[g]``
@@ -157,21 +158,17 @@ def sweep_grid(rects, sizes, *, m: int, interpret: bool = True):
             jax.ShapeDtypeStruct((Gp, NX), jnp.int32),
             jax.ShapeDtypeStruct((Gp, NX), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rects[..., 0], rects[..., 1], rects[..., 2], rects[..., 3], sizes)
     return hot[:G], xs[:G], ys[:G]
 
 
-def sweep_small_batch_device(arr: np.ndarray, sizes: np.ndarray, m: int, *,
-                             interpret: bool | None = None
+def sweep_small_batch_device(arr: np.ndarray, sizes: np.ndarray, m: int
                              ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Host-array wrapper: (G, S, 4) rect rows -> (hot bool (G, NX-1, NX-1),
     xs (G, NX), ys (G, NX)) as NumPy, ready for ``_extract_runs``."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     hot, xs, ys = sweep_grid(jnp.asarray(arr, jnp.int32),
-                             jnp.asarray(sizes, jnp.int32),
-                             m=int(m), interpret=bool(interpret))
+                             jnp.asarray(sizes, jnp.int32), m=int(m))
     NX = xs.shape[1]
     # cast to bool on-device: the coverage grid crosses the bus at one
     # byte per cell instead of four

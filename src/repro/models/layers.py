@@ -14,13 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-if hasattr(jax, "shard_map"):                       # jax >= 0.6
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-else:                                               # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 NEG_INF = -1e30
 
 
@@ -352,12 +345,12 @@ def moe_shardmap(x, router_w, w1, w3, w2, top_k: int,
                              top_k)
         return lax.all_gather(out, "model", axis=1, tiled=True)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
-        out_specs=P(batch, None, None), **_SHARD_MAP_NOCHECK)
+        out_specs=P(batch, None, None), check_vma=False)
     return fn(x, router_w, w1, w3, w2)
 
 

@@ -71,7 +71,10 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     from repro.api import Aligner
+    from repro.compile_cache import configure_compile_cache
     from repro.serve import AlignServer, CompactionSupervisor
+
+    configure_compile_cache()
 
     wal = False
     if args.wal:

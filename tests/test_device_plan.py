@@ -163,24 +163,30 @@ def test_residency_invalidated_by_compaction(tmp_path):
 
 
 def test_oversized_arena_caches_host_fallback(monkeypatch):
+    # an arena the device probe cannot address is an error under the
+    # device plan, never a batch quietly served on the host; the cpu plan
+    # still serves the same store
     rng = np.random.default_rng(5)
     docs = _corpus(rng)
     frozen = _frozen("multiset", docs)
     qs = _queries(rng, docs)
     cpu = _batch_blocks(batch_query(frozen, qs, 0.5,
                                     options=QueryOptions(plan="cpu")))
-    # pretend the CSR extent overflows the kernel's int32 offsets
+    # pretend the CSR extent overflows the probe's int32 offsets
     monkeypatch.setattr(dp, "_I32_MAX", -1)
     reset_transfer_stats()
-    opts = QueryOptions(plan="device")
-    for _ in range(2):
-        got = _batch_blocks(batch_query(frozen, qs, 0.5, options=opts))
-        assert got == cpu                         # host fallback, same blocks
+    for probe_backend in (None, "device"):       # fused and pinned probe
+        opts = QueryOptions(plan="device", probe_backend=probe_backend,
+                            sweep=None if probe_backend is None
+                            else "grouped")
+        with pytest.raises(dp.DeviceArenaError, match="int32"):
+            batch_query(frozen, qs, 0.5, options=opts)
     st = transfer_stats()
-    assert st["arena_uploads"] == 0
+    assert st["arena_uploads"] == 0 and st["batches"] == 0
     assert st["h2d_bytes"] == 0 and st["d2h_bytes"] == 0
-    # the None outcome is cached: no rebuild attempt per batch
-    assert frozen._device_arena == (frozen.arena(), None)
+    assert getattr(frozen, "_device_arena", None) is None
+    assert _batch_blocks(batch_query(
+        frozen, qs, 0.5, options=QueryOptions(plan="cpu"))) == cpu
 
 
 # --------------------------------------------------------------------------
@@ -281,3 +287,60 @@ def test_mixing_options_and_legacy_kwargs_is_an_error():
     with pytest.raises(TypeError, match="both"):
         batch_query(frozen, [docs[0][:20]], 0.5,    # repro: allow[RPR404]
                     options=QueryOptions(plan="cpu"), sweep="loop")
+
+
+# --------------------------------------------------------------------------
+# no hidden fallbacks: interpret mode, backend errors, host-swept groups
+# --------------------------------------------------------------------------
+
+def test_interpret_decision_has_one_owner(monkeypatch):
+    import jax
+
+    from repro.kernels.interpret import resolve_interpret
+    assert resolve_interpret() is (jax.default_backend() != "tpu")
+    assert resolve_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret() is False           # compiled on a TPU
+    with pytest.raises(ValueError, match="TPU"):
+        resolve_interpret(True)
+
+
+def test_auto_plan_lets_backend_errors_through(monkeypatch):
+    import jax
+
+    def broken():
+        raise RuntimeError("backend failed to initialise")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="initialise"):
+        resolve_plan(QueryOptions(plan="auto"))
+
+
+@pytest.mark.parametrize("live", [False, True])
+def test_device_plan_counts_probes_and_host_swept_groups(live, tmp_path):
+    from repro.core.query import (_SMALL_GROUP_MAX, _group_bounds,
+                                  batch_probe)
+    rng = np.random.default_rng(10)
+    docs = _corpus(rng, n=120)
+    frozen = _frozen("multiset", docs)
+    index = frozen
+    if live:
+        save_index(frozen, tmp_path / "idx")
+        index = LiveIndex.open(tmp_path / "idx")
+    qs = [d[:100].copy() for d in docs[:4]] + _queries(rng, docs)
+    m = int(np.ceil(8 * 0.5))
+    q, w, c = batch_probe(frozen, frozen.scheme.sketch_batch(qs))
+    _, g_lo, g_hi, distinct = _group_bounds(q, w[:, 0], c)
+    kept = distinct >= m
+    large = int((kept & (g_hi - g_lo > _SMALL_GROUP_MAX)).sum())
+    assert large and (kept & (g_hi - g_lo <= _SMALL_GROUP_MAX)).any()
+    reset_transfer_stats()
+    opts = QueryOptions(plan="device")
+    got = (index.batch_query(qs, 0.5, options=opts) if live
+           else batch_query(index, qs, 0.5, options=opts))
+    st = transfer_stats()
+    assert st["batches"] == 1                     # one resident-arena probe
+    assert st["host_large_groups"] == large
+    assert st["sweep_launches"] >= 1
+    assert _batch_blocks(got) == _batch_blocks(
+        batch_query(frozen, qs, 0.5, options=QueryOptions(plan="cpu")))

@@ -566,6 +566,10 @@ def test_supervisor_auto_compacts_and_prunes(tmp_path):
             assert _wait_for(
                 lambda: client.healthz()["generation"] >= 1), \
                 "supervisor never compacted"
+            # the counter moves after the off-band prune that follows the
+            # promotion, so the new generation can be visible before it
+            _wait_for(lambda: client.metrics()["counters"][
+                "supervisor_compactions_total"] >= 1)
             snap = client.metrics()
             assert snap["counters"]["supervisor_compactions_total"] >= 1
             assert snap["counters"]["supervisor_failures_total"] == 0
