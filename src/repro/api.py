@@ -51,6 +51,7 @@ from .core.results import (UNSET, Match, QueryOptions, QueryResult,
                            coerce_query_options)
 from .core.search import SearchIndex
 from .core.sharded_index import ShardedAlignmentIndex
+from .core.spans import span
 from .core.store import (CURRENT_POINTER, load_index, read_manifest,
                          save_index)
 from .core.weights import WeightFn
@@ -269,8 +270,9 @@ class Aligner:
         ``DeprecationWarning`` (they coerce to pins on the cpu plan), as
         does ``legacy_tuples=True`` for the old ``list[list[Alignment]]``
         return shape.  ``stage_times`` accumulates per-stage wall seconds
-        under ``"sketch"``/``"probe"``/``"sweep"`` (the serve-path metrics
-        hook)."""
+        under ``"sketch"``/``"probe"``/``"sweep"``, their children and
+        ``"results"`` (the names of :mod:`repro.core.spans`; the
+        serve-path metrics hook)."""
         opts = coerce_query_options(options, "Aligner.find_batch",
                                     backend=backend,
                                     sketch_backend=sketch_backend,
@@ -298,14 +300,15 @@ class Aligner:
                 DeprecationWarning, stacklevel=2)
             return res
         k = self.scheme.k
-        results = [QueryResult.from_alignments(r, theta=theta, k=k,
-                                               query_len=len(t))
-                   for r, t in zip(res, tokens)]
-        if failed:
-            fs = tuple(sorted(set(failed)))
-            results = [dataclasses.replace(r, degraded=True,
-                                           failed_shards=fs)
-                       for r in results]
+        with span(stage_times, "results"):
+            results = [QueryResult.from_alignments(r, theta=theta, k=k,
+                                                   query_len=len(t))
+                       for r, t in zip(res, tokens)]
+            if failed:
+                fs = tuple(sorted(set(failed)))
+                results = [dataclasses.replace(r, degraded=True,
+                                               failed_shards=fs)
+                           for r in results]
         return results
 
     # -- persistence --------------------------------------------------------

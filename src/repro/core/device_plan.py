@@ -42,11 +42,14 @@ bit-identical to ``plan="cpu"`` — gated in ``tests/test_device_plan.py``.
 ``transfer_stats()`` exposes logical host<->device byte counters (what
 crosses the bus on a real accelerator; in interpret mode the same arrays
 flow, uncounted copies aside) for the residency tests and the roofline
-benchmark's fused-pipeline row, plus three work counters: ``batches``
+benchmark's fused-pipeline row, plus work counters: ``batches``
 (probes of a resident arena, one per batch and frozen level),
-``sweep_launches`` (device sweep launches) and ``host_large_groups``
-(groups of more than ``_SMALL_GROUP_MAX`` windows, which the device plan
-sweeps on the host by design).
+``sweep_launches`` (device sweep launches), ``probe_windows`` (windows
+the probe gathered, the grouping's input), ``groups_kept`` ((query,
+text) groups with at least ⌈kθ⌉ distinct coordinates),
+``host_large_groups`` (kept groups of more than ``_SMALL_GROUP_MAX``
+windows, which the device plan sweeps on the host by design) and
+``host_large_windows`` (the windows of those groups).
 """
 
 from __future__ import annotations
@@ -57,6 +60,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from .frozen import MODE_PACKED, PACK_SHIFT, _concat_ranges
+from .spans import add_seconds, span
 
 __all__ = ["DeviceArena", "DeviceArenaError", "device_arena",
            "resident_probe", "fused_batch_query", "transfer_stats",
@@ -67,10 +71,11 @@ _I32_MAX = np.iinfo(np.int32).max
 # logical host<->device transfer accounting (bytes that cross the bus on
 # a real accelerator).  arena_* count the once-per-generation residency
 # upload; h2d/d2h count the per-batch steady-state traffic; the rest
-# count device probes, device sweep launches and host-swept large groups.
+# count device probes, device sweep launches and the grouping's work.
 _STATS = {"arena_uploads": 0, "arena_bytes": 0,
           "h2d_bytes": 0, "d2h_bytes": 0, "batches": 0,
-          "sweep_launches": 0, "host_large_groups": 0}
+          "sweep_launches": 0, "probe_windows": 0, "groups_kept": 0,
+          "host_large_groups": 0, "host_large_windows": 0}
 
 
 def transfer_stats() -> dict:
@@ -85,7 +90,7 @@ def reset_transfer_stats() -> None:
 
 def add_counts(**counts: int) -> None:
     """Add to the module's counters (the device sweep's callers report
-    ``sweep_launches`` and ``host_large_groups`` through here)."""
+    ``sweep_launches`` and the grouping's counts through here)."""
     for key, n in counts.items():
         _STATS[key] += int(n)
 
@@ -219,8 +224,12 @@ def _encode_queries(mode: str, pkeys: np.ndarray, coords: np.ndarray,
     return qhi, qlo, qtag
 
 
-def _device_probe(da: DeviceArena, pkeys, coords, valid
+def _device_probe(da: DeviceArena, pkeys, coords, valid,
+                  times: dict | None = None
                   ) -> tuple[np.ndarray, np.ndarray]:
+    """The probe call on the resident arena; the call through its
+    blocking read-back is the ``probe.device`` span (timed into
+    ``times`` when given)."""
     global _PROBE_JIT
     if _PROBE_JIT is None:
         _PROBE_JIT = _probe_jit_factory()
@@ -231,14 +240,15 @@ def _device_probe(da: DeviceArena, pkeys, coords, valid
         return z, z
     qhi, qlo, qtag = _encode_queries(da.mode, pkeys, coords, valid)
     valid = np.ascontiguousarray(valid, bool)
-    starts, ends = _PROBE_JIT(
-        da.khi, da.klo, da.ktag, da.offsets,
-        jnp.asarray(qhi), jnp.asarray(qlo), jnp.asarray(qtag),
-        jnp.asarray(valid))
+    with span(times, "probe.device"):
+        starts, ends = _PROBE_JIT(
+            da.khi, da.klo, da.ktag, da.offsets,
+            jnp.asarray(qhi), jnp.asarray(qlo), jnp.asarray(qtag),
+            jnp.asarray(valid))
+        starts = np.asarray(starts, np.int64)
+        ends = np.asarray(ends, np.int64)
     _STATS["h2d_bytes"] += (qhi.nbytes + qlo.nbytes + qtag.nbytes +
                             valid.nbytes)
-    starts = np.asarray(starts, np.int64)
-    ends = np.asarray(ends, np.int64)
     _STATS["d2h_bytes"] += 2 * len(pkeys) * 4        # i32 starts + ends
     return starts, ends
 
@@ -262,82 +272,91 @@ def fused_batch_query(index, sketches, B: int, m: int, *,
     column read — no transfer), device gather of the rectangle rows from
     the resident ``win_rect``, device sweep, and block extraction from
     the compressed coverage grids.  Block-identical to the cpu plan.
+    ``stage_times`` accumulates the ``probe`` and ``sweep`` spans and
+    their children (:mod:`repro.core.spans`).
     """
-    from .query import (_SIZE_BUCKETS, _SMALL_GROUP_MAX, Alignment,
-                        _extract_runs, _group_bounds, _sweep_text)
-    t1 = time.perf_counter()
-    arena = index.arena()
-    k = arena.k
-    pkeys, coords, valid = arena.encode_batch(sketches)
-    da = device_arena(index)
-    starts, ends = _device_probe(da, pkeys, coords, valid)
-    counts = ends - starts
-    row_ids = _concat_ranges(starts, counts)
-    probe_ids = np.repeat(np.arange(len(pkeys), dtype=np.int64), counts)
-    qid_all, cid_all = probe_ids // k, probe_ids % k
-    # the ONE window column the host touches: text ids, for grouping and
-    # result labelling (mmap page-ins, not bus traffic)
-    tid_all = np.asarray(arena.windows[row_ids, 0], np.int64)
-    t2 = time.perf_counter()
+    with span(stage_times, "probe"):
+        arena = index.arena()
+        k = arena.k
+        pkeys, coords, valid = arena.encode_batch(sketches)
+        da = device_arena(index)
+        starts, ends = _device_probe(da, pkeys, coords, valid, stage_times)
+        with span(stage_times, "probe.gather"):
+            counts = ends - starts
+            row_ids = _concat_ranges(starts, counts)
+            probe_ids = np.repeat(np.arange(len(pkeys), dtype=np.int64),
+                                  counts)
+            qid_all, cid_all = probe_ids // k, probe_ids % k
+            # the ONE window column the host touches: text ids, for
+            # grouping and result labelling (mmap page-ins, not bus
+            # traffic)
+            tid_all = np.asarray(arena.windows[row_ids, 0], np.int64)
+    with span(stage_times, "sweep"):
+        if not len(row_ids):
+            return [[] for _ in range(B)]
+        return _fused_sweep(arena, da, row_ids, qid_all, tid_all, cid_all,
+                            B, m, stage_times)
 
-    results: list[list[Alignment]] = [[] for _ in range(B)]
-    if len(qid_all):
-        import jax.numpy as jnp
 
-        from ..kernels.sweep_grid import sweep_grid
+def _fused_sweep(arena, da: DeviceArena, row_ids, qid_all, tid_all,
+                 cid_all, B: int, m: int, times: dict | None) -> list:
+    """The sweep stage of the fused path: group, sweep the small groups
+    on the device and the large ones on the host straight off the mmap
+    rows, then emit."""
+    import jax.numpy as jnp
+
+    from ..kernels.sweep_grid import sweep_grid
+    from .query import (_SIZE_BUCKETS, _SMALL_GROUP_MAX, _emit,
+                        _extract_runs, _group_bounds, _pad_groups,
+                        _sweep_text)
+    with span(times, "sweep.group"):
         order, g_starts, g_ends, distinct = _group_bounds(
             qid_all, tid_all, cid_all)
         qid_s, tid_s, row_s = qid_all[order], tid_all[order], row_ids[order]
-        keep = distinct >= m
         sizes = g_ends - g_starts
+        kept = np.flatnonzero(distinct >= m)
+        is_small = sizes[kept] <= _SMALL_GROUP_MAX
+        small, large = kept[is_small], kept[~is_small]
 
-        small_results: dict[int, list] = {}
-        sm_ids = np.flatnonzero(keep & (sizes <= _SMALL_GROUP_MAX))
-        for b_lo, b_hi in _SIZE_BUCKETS:
-            ids = sm_ids[(sizes[sm_ids] > b_lo) & (sizes[sm_ids] <= b_hi)]
-            if not len(ids):
-                continue
-            s_starts, s_sizes = g_starts[ids], sizes[ids]
-            G, S = len(ids), int(s_sizes.max())
-            idx = np.zeros((G, S), np.int32)
-            rows = row_s[_concat_ranges(s_starts, s_sizes)]
-            slot = np.arange(len(rows)) - np.repeat(
-                np.cumsum(s_sizes) - s_sizes, s_sizes)
-            idx[np.repeat(np.arange(G), s_sizes), slot] = rows
-            sz32 = s_sizes.astype(np.int32)
+    grids = []                  # (group ids, hot, xs, ys) per size bucket
+    for b_lo, b_hi in _SIZE_BUCKETS:
+        ids = small[(sizes[small] > b_lo) & (sizes[small] <= b_hi)]
+        if not len(ids):
+            continue
+        idx = _pad_groups(row_s, g_starts[ids], sizes[ids]).astype(np.int32)
+        sz32 = sizes[ids].astype(np.int32)
+        with span(times, "sweep.device"):
             # device-side row gather from the resident rectangle columns:
             # only the (G, S) index grid goes up, never the window rows
             rects = jnp.take(da.win_rect, jnp.asarray(idx), axis=0)
             hot, xs, ys = sweep_grid(rects, jnp.asarray(sz32), m=m)
-            _STATS["sweep_launches"] += 1
-            _STATS["h2d_bytes"] += idx.nbytes + sz32.nbytes
             NX = int(xs.shape[1])
             # bool-cast on device: the grid crosses at 1 byte per cell
             hot_np = np.asarray(hot[:, :NX - 1, :NX - 1].astype(jnp.bool_))
             xs_np = np.asarray(xs, np.int64)
             ys_np = np.asarray(ys, np.int64)
-            _STATS["d2h_bytes"] += hot_np.size + 2 * xs_np.size * 4  # b8/i32
-            for g, blocks in zip(ids, _extract_runs(hot_np, xs_np, ys_np)):
-                small_results[int(g)] = blocks
+        _STATS["sweep_launches"] += 1
+        _STATS["h2d_bytes"] += idx.nbytes + sz32.nbytes
+        _STATS["d2h_bytes"] += hot_np.size + 2 * xs_np.size * 4  # b8/i32
+        grids.append((ids, hot_np, xs_np, ys_np))
 
-        kept = np.flatnonzero(keep)
-        _STATS["host_large_groups"] += len(kept) - len(small_results)
-        for g in kept:
-            g = int(g)
-            lo = g_starts[g]
-            if g in small_results:
-                blocks = small_results[g]
-            else:
-                # rare large group: host sweep straight off the mmap rows
-                blocks = _sweep_text(
-                    np.asarray(arena.windows[row_s[lo:g_ends[g]], 1:5],
-                               np.int64), m)
-            if blocks:
-                results[int(qid_s[lo])].append(
-                    Alignment(text_id=int(tid_s[lo]), blocks=blocks,
-                              ncoords=int(distinct[g])))
-    if stage_times is not None:
-        t3 = time.perf_counter()
-        stage_times["probe"] = stage_times.get("probe", 0.0) + (t2 - t1)
-        stage_times["sweep"] = stage_times.get("sweep", 0.0) + (t3 - t2)
-    return results
+    blocks: dict[int, list] = {}
+    with span(times, "sweep.large"):
+        read = 0.0
+        for g in large.tolist():
+            # large group: host sweep straight off the mmap rows
+            t = time.perf_counter()
+            rows = np.asarray(arena.windows[row_s[g_starts[g]:g_ends[g]],
+                                            1:5], np.int64)
+            read += time.perf_counter() - t
+            blocks[g] = _sweep_text(rows, m)
+        add_seconds(times, "sweep.large.read", read)
+    add_counts(probe_windows=len(row_ids), groups_kept=len(kept),
+               host_large_groups=len(large),
+               host_large_windows=sizes[large].sum())
+
+    with span(times, "sweep.emit"):
+        for ids, hot_np, xs_np, ys_np in grids:
+            blocks.update(zip(ids.tolist(),
+                              _extract_runs(hot_np, xs_np, ys_np)))
+        return _emit(kept, blocks, g_starts, qid_s, tid_s, distinct, B)

@@ -55,6 +55,7 @@ from .query import (Alignment, _sweep_gathered, batch_probe as _batch_probe,
                     query as _query)
 from .results import UNSET, QueryOptions, coerce_query_options
 from .search import SearchIndex
+from .spans import span
 
 
 @dataclass
@@ -352,7 +353,8 @@ class LiveIndex:
         ``sketches``/``backend``/``probe_backend``/``sweep`` keywords
         still work behind a ``DeprecationWarning``.  ``stage_times``
         accumulates per-stage wall seconds under
-        ``"sketch"``/``"probe"``/``"sweep"`` when given.
+        ``"sketch"``/``"probe"``/``"sweep"`` and their children
+        (:mod:`repro.core.spans`) when given.
         """
         opts = coerce_query_options(options, "LiveIndex.batch_query",
                                     sketches=sketches, backend=backend,
@@ -360,26 +362,21 @@ class LiveIndex:
         xp = resolve_plan(opts)
         if not len(texts):
             return []
-        t0 = time.perf_counter()
-        sk = opts.sketches
-        if sk is None:
-            sk = self.scheme.sketch_batch(texts, backend=xp.sketch_backend)
-        m = max(1, math.ceil(self.scheme.k * theta))
-        t1 = time.perf_counter()
-        gathered = self.batch_probe(sk, probe_backend=xp.probe_backend)
-        t2 = time.perf_counter()
-        out = [sorted((Alignment(text_id=self.doc_map[al.text_id],
-                                 blocks=al.blocks, ncoords=al.ncoords)
-                       for al in res),
-                      key=lambda a: a.text_id)
-               for res in _sweep_gathered(gathered, len(texts), m,
-                                          xp.sweep)]
-        if stage_times is not None:
-            t3 = time.perf_counter()
-            stage_times["sketch"] = stage_times.get("sketch", 0.) + (t1 - t0)
-            stage_times["probe"] = stage_times.get("probe", 0.) + (t2 - t1)
-            stage_times["sweep"] = stage_times.get("sweep", 0.) + (t3 - t2)
-        return out
+        with span(stage_times, "sketch"):
+            sk = opts.sketches
+            if sk is None:
+                sk = self.scheme.sketch_batch(texts,
+                                              backend=xp.sketch_backend)
+            m = max(1, math.ceil(self.scheme.k * theta))
+        with span(stage_times, "probe"):
+            gathered = self.batch_probe(sk, probe_backend=xp.probe_backend)
+        with span(stage_times, "sweep"):
+            return [sorted((Alignment(text_id=self.doc_map[al.text_id],
+                                      blocks=al.blocks, ncoords=al.ncoords)
+                            for al in res),
+                           key=lambda a: a.text_id)
+                    for res in _sweep_gathered(gathered, len(texts), m,
+                                               xp.sweep, stage_times)]
 
     # -- compaction ---------------------------------------------------------
 

@@ -23,7 +23,6 @@ Two execution paths over the same algorithm:
 from __future__ import annotations
 
 import math
-import time
 from collections import defaultdict
 from dataclasses import dataclass
 
@@ -32,6 +31,7 @@ import numpy as np
 from .frozen import _concat_ranges
 from .plan import resolve_plan
 from .results import UNSET, QueryOptions, coerce_query_options
+from .spans import span
 
 
 @dataclass
@@ -314,8 +314,9 @@ def batch_query(index, queries, theta: float, *,
     ``DeprecationWarning``); they coerce to pins on the cpu plan.
 
     ``stage_times``, when given, accumulates per-stage wall seconds under
-    the keys ``"sketch"``, ``"probe"`` and ``"sweep"`` (the serve-path
-    metrics hook; += so one dict can span many batches).
+    the keys ``"sketch"``, ``"probe"`` and ``"sweep"`` and their children
+    (the names of :mod:`repro.core.spans`; the serve-path metrics hook;
+    += so one dict can span many batches).
     """
     opts = coerce_query_options(options, "batch_query", sketches=sketches,
                                 sketch_backend=sketch_backend,
@@ -325,26 +326,18 @@ def batch_query(index, queries, theta: float, *,
     if B == 0:
         return []
     m = max(1, math.ceil(index.scheme.k * theta))
-    t0 = time.perf_counter()
-    sk = opts.sketches
-    if sk is None:
-        sk = index.scheme.sketch_batch(queries, backend=xp.sketch_backend)
-    t1 = time.perf_counter()
+    with span(stage_times, "sketch"):
+        sk = opts.sketches
+        if sk is None:
+            sk = index.scheme.sketch_batch(queries,
+                                           backend=xp.sketch_backend)
     if xp.fused and getattr(index, "is_frozen", False):
         from .device_plan import fused_batch_query
-        out = fused_batch_query(index, sk, B, m, stage_times=stage_times)
-        if stage_times is not None:
-            stage_times["sketch"] = stage_times.get("sketch", 0.0) + (t1 - t0)
-        return out
-    gathered = batch_probe(index, sk, probe_backend=xp.probe_backend)
-    t2 = time.perf_counter()
-    out = _sweep_gathered(gathered, B, m, xp.sweep)
-    if stage_times is not None:
-        t3 = time.perf_counter()
-        stage_times["sketch"] = stage_times.get("sketch", 0.0) + (t1 - t0)
-        stage_times["probe"] = stage_times.get("probe", 0.0) + (t2 - t1)
-        stage_times["sweep"] = stage_times.get("sweep", 0.0) + (t3 - t2)
-    return out
+        return fused_batch_query(index, sk, B, m, stage_times=stage_times)
+    with span(stage_times, "probe"):
+        gathered = batch_probe(index, sk, probe_backend=xp.probe_backend)
+    with span(stage_times, "sweep"):
+        return _sweep_gathered(gathered, B, m, xp.sweep, stage_times)
 
 
 def batch_probe(index, sketches, *, probe_backend: str = "numpy"
@@ -414,60 +407,82 @@ def _group_bounds(qid_all: np.ndarray, tid_all: np.ndarray,
 _SIZE_BUCKETS = ((0, 8), (8, 16), (16, _SMALL_GROUP_MAX))
 
 
-def _sweep_gathered(gathered, B: int, m: int, sweep: str
-                    ) -> list[list[Alignment]]:
-    """Group the gathered windows by (query, text) and plane-sweep each
-    group (the second stage of ``batch_query``)."""
-    qid_all, win_all, cid_all = gathered
+def _pad_groups(values: np.ndarray, starts: np.ndarray, sizes: np.ndarray
+                ) -> np.ndarray:
+    """(G, S, ...) grid of the G groups ``values[starts[g]:starts[g] +
+    sizes[g]]``, zero past each group's size (S is the largest size)."""
+    G, S = len(sizes), int(sizes.max())
+    out = np.zeros((G, S) + values.shape[1:], values.dtype)
+    rows = values[_concat_ranges(starts, sizes)]
+    slot = np.arange(len(rows)) - np.repeat(np.cumsum(sizes) - sizes, sizes)
+    out[np.repeat(np.arange(G), sizes), slot] = rows
+    return out
+
+
+def _emit(kept: np.ndarray, blocks: dict, starts: np.ndarray,
+          qid_s: np.ndarray, tid_s: np.ndarray, distinct: np.ndarray,
+          B: int) -> list[list[Alignment]]:
+    """Each query's alignments: the kept groups (ascending, so in text-id
+    order) whose sweep found blocks."""
     results: list[list[Alignment]] = [[] for _ in range(B)]
-    if not len(qid_all):
-        return results
-
-    order, starts, ends, distinct = _group_bounds(
-        qid_all, win_all[:, 0], cid_all)
-    qid_all, win_all = qid_all[order], win_all[order]
-    keep = distinct >= m
-    sizes = ends - starts
-
-    small_results: dict[int, list] = {}
-    if sweep in ("grouped", "device"):
-        sm_ids = np.flatnonzero(keep & (sizes <= _SMALL_GROUP_MAX))
-        for b_lo, b_hi in _SIZE_BUCKETS:
-            ids = sm_ids[(sizes[sm_ids] > b_lo) & (sizes[sm_ids] <= b_hi)]
-            if not len(ids):
-                continue
-            s_starts, s_sizes = starts[ids], sizes[ids]
-            G, S = len(ids), int(s_sizes.max())
-            arr = np.zeros((G, S, 4), np.int64)
-            rows = win_all[_concat_ranges(s_starts, s_sizes), 1:5]
-            slot = np.arange(len(rows)) - np.repeat(
-                np.cumsum(s_sizes) - s_sizes, s_sizes)
-            arr[np.repeat(np.arange(G), s_sizes), slot] = rows
-            if sweep == "device":
-                from ..kernels.sweep_grid import sweep_small_batch_device
-                from .device_plan import add_counts
-                batched = _extract_runs(
-                    *sweep_small_batch_device(arr, s_sizes, m))
-                add_counts(sweep_launches=1)
-            else:
-                batched = _sweep_small_batch(arr, s_sizes, m)
-            for g, blocks in zip(ids, batched):
-                small_results[int(g)] = blocks
-
-    kept = np.flatnonzero(keep)
-    if sweep == "device":
-        from .device_plan import add_counts
-        add_counts(host_large_groups=len(kept) - len(small_results))
-    for g in kept:
-        g = int(g)
-        lo = starts[g]
-        blocks = small_results[g] if g in small_results else \
-            _sweep_text(win_all[lo:ends[g], 1:5], m)
-        if blocks:
-            results[int(qid_all[lo])].append(
-                Alignment(text_id=int(win_all[lo, 0]), blocks=blocks,
+    for g in kept.tolist():
+        if blocks[g]:
+            lo = starts[g]
+            results[int(qid_s[lo])].append(
+                Alignment(text_id=int(tid_s[lo]), blocks=blocks[g],
                           ncoords=int(distinct[g])))
     return results
+
+
+def _sweep_gathered(gathered, B: int, m: int, sweep: str,
+                    times: dict | None = None) -> list[list[Alignment]]:
+    """Group the gathered windows by (query, text) and plane-sweep each
+    group (the second stage of ``batch_query``); ``times`` accumulates
+    the ``sweep.*`` spans."""
+    qid_all, win_all, cid_all = gathered
+    if not len(qid_all):
+        return [[] for _ in range(B)]
+
+    with span(times, "sweep.group"):
+        order, starts, ends, distinct = _group_bounds(
+            qid_all, win_all[:, 0], cid_all)
+        qid_all, win_all = qid_all[order], win_all[order]
+        sizes = ends - starts
+        kept = np.flatnonzero(distinct >= m)
+        is_small = (sizes[kept] <= _SMALL_GROUP_MAX) & \
+            (sweep in ("grouped", "device"))
+        small, large = kept[is_small], kept[~is_small]
+
+    blocks: dict[int, list] = {}
+    grids = []                  # (group ids, hot, xs, ys) per size bucket
+    for b_lo, b_hi in _SIZE_BUCKETS:
+        ids = small[(sizes[small] > b_lo) & (sizes[small] <= b_hi)]
+        if not len(ids):
+            continue
+        arr = _pad_groups(win_all[:, 1:5], starts[ids], sizes[ids])
+        if sweep == "device":
+            from ..kernels.sweep_grid import sweep_small_batch_device
+            with span(times, "sweep.device"):
+                grids.append((ids, *sweep_small_batch_device(
+                    arr, sizes[ids], m)))
+        else:
+            blocks.update(zip(ids.tolist(),
+                              _sweep_small_batch(arr, sizes[ids], m)))
+
+    with span(times, "sweep.large"):
+        for g in large.tolist():
+            blocks[g] = _sweep_text(win_all[starts[g]:ends[g], 1:5], m)
+    if sweep == "device":
+        from .device_plan import add_counts
+        add_counts(sweep_launches=len(grids), probe_windows=len(qid_all),
+                   groups_kept=len(kept), host_large_groups=len(large),
+                   host_large_windows=sizes[large].sum())
+
+    with span(times, "sweep.emit"):
+        for ids, hot, xs, ys in grids:
+            blocks.update(zip(ids.tolist(), _extract_runs(hot, xs, ys)))
+        return _emit(kept, blocks, starts, qid_all, win_all[:, 0], distinct,
+                     B)
 
 
 def estimate_similarity(index, query_tokens, data_tokens

@@ -48,6 +48,7 @@ from .plan import resolve_plan
 from .query import Alignment, _sweep_gathered, batch_probe, query
 from .results import UNSET, QueryOptions, coerce_query_options
 from .search import SearchIndex
+from .spans import span
 
 META_VERSION = 1
 
@@ -253,7 +254,9 @@ class ShardedAlignmentIndex:
         already holds the batch's sketch coordinates (shards share the
         hash family, so they are computed once regardless).
         ``stage_times`` accumulates per-stage wall seconds under
-        ``"sketch"``/``"probe"``/``"sweep"`` when given.
+        ``"sketch"``/``"probe"``/``"sweep"`` and the sweep's children
+        (:mod:`repro.core.spans`) when given; the pool threads of the
+        probe fan-out write into no shared dict.
 
         **Degraded mode**: with ``failures`` set to a caller-owned list,
         a shard whose probe keeps raising after ``shard_retries`` bounded
@@ -269,15 +272,17 @@ class ShardedAlignmentIndex:
         xp = resolve_plan(opts)
         if not texts:
             return []
-        t0 = time.perf_counter()
-        sk = opts.sketches
-        if sk is None:
-            sk = self.scheme.sketch_batch(texts, backend=xp.sketch_backend)
-        inverse = self._inverse_doc_map()
-        B = len(texts)
-        m = max(1, math.ceil(self.scheme.k * theta))
+        with span(stage_times, "sketch"):
+            sk = opts.sketches
+            if sk is None:
+                sk = self.scheme.sketch_batch(texts,
+                                              backend=xp.sketch_backend)
+            inverse = self._inverse_doc_map()
+            B = len(texts)
+            m = max(1, math.ceil(self.scheme.k * theta))
 
         def probe_shard(s_shard):
+            # runs on the fan-out pool: writes into no shared stage dict
             s, shard = s_shard
             attempts = 1 + (shard_retries if failures is not None else 0)
             delay = retry_backoff_s
@@ -295,32 +300,27 @@ class ShardedAlignmentIndex:
                     time.sleep(delay)
                     delay *= 2
 
-        t1 = time.perf_counter()
-        if xp.fanout == "threaded" and self.n_shards > 1:
-            gathered = list(self._fanout_pool().map(probe_shard,
-                                                    enumerate(self.shards)))
-        else:
-            gathered = [probe_shard(s) for s in enumerate(self.shards)]
-        t2 = time.perf_counter()
-        # a failed (skipped) shard contributes an empty result per query
-        shard_results = [_sweep_gathered(g, B, m, xp.sweep)
-                         if g is not None else [[] for _ in texts]
-                         for g in gathered]
-
-        per_q: list[list[Alignment]] = [[] for _ in texts]
-        for s, res in enumerate(shard_results):
-            for qi, als in enumerate(res):
-                per_q[qi].extend(
-                    Alignment(text_id=inverse[(s, al.text_id)],
-                              blocks=al.blocks, ncoords=al.ncoords)
-                    for al in als)
-        out = [sorted(r, key=lambda a: a.text_id) for r in per_q]
-        if stage_times is not None:
-            t3 = time.perf_counter()
-            stage_times["sketch"] = stage_times.get("sketch", 0.) + (t1 - t0)
-            stage_times["probe"] = stage_times.get("probe", 0.) + (t2 - t1)
-            stage_times["sweep"] = stage_times.get("sweep", 0.) + (t3 - t2)
-        return out
+        with span(stage_times, "probe"):
+            if xp.fanout == "threaded" and self.n_shards > 1:
+                gathered = list(self._fanout_pool().map(
+                    probe_shard, enumerate(self.shards)))
+            else:
+                gathered = [probe_shard(s) for s in enumerate(self.shards)]
+        with span(stage_times, "sweep"):
+            # a failed (skipped) shard contributes an empty result per
+            # query; the shards sweep on this thread, one after another
+            shard_results = [
+                _sweep_gathered(g, B, m, xp.sweep, stage_times)
+                if g is not None else [[] for _ in texts]
+                for g in gathered]
+            per_q: list[list[Alignment]] = [[] for _ in texts]
+            for s, res in enumerate(shard_results):
+                for qi, als in enumerate(res):
+                    per_q[qi].extend(
+                        Alignment(text_id=inverse[(s, al.text_id)],
+                                  blocks=al.blocks, ncoords=al.ncoords)
+                        for al in als)
+            return [sorted(r, key=lambda a: a.text_id) for r in per_q]
 
     def freeze(self) -> "ShardedAlignmentIndex":
         """Freeze every shard into the CSR serving layout (idempotent).

@@ -37,10 +37,12 @@ import base64
 import hashlib
 import json
 import struct
+from contextlib import contextmanager
 
 from .. import fault
 from ..core.live import LiveIndex
 from ..core.sharded_index import ShardedAlignmentIndex
+from ..core.spans import span
 from ..core.store import store_counters
 from .batcher import DeadlineExceeded, DynamicBatcher, QueueFull
 from .metrics import ServeMetrics
@@ -122,12 +124,25 @@ class AlignServer:
 
     # -- endpoint bodies (shared by HTTP and WebSocket) ----------------------
 
-    async def handle_query(self, body) -> tuple[int, bytes]:
+    @contextmanager
+    def _span(self, name: str):
+        """A front-end span on the event loop: annotated and timed as the
+        engine's are (:mod:`repro.core.spans`), its seconds handed to the
+        metrics under their lock, never into a batch's stage dict."""
+        times: dict = {}
         try:
-            req = parse_query_request(body)
-            tokens = self.aligner._tokens(req.text)
-        except (ProtocolError, ValueError) as e:
-            return 400, error_response(str(e), 400)
+            with span(times, name):
+                yield
+        finally:
+            self.metrics.observe_span(name, times[name])
+
+    async def handle_query(self, body) -> tuple[int, bytes]:
+        with self._span("serve.parse"):
+            try:
+                req = parse_query_request(body)
+                tokens = self.aligner._tokens(req.text)
+            except (ProtocolError, ValueError) as e:
+                return 400, error_response(str(e), 400)
 
         def err(message: str, status: int) -> tuple[int, bytes]:
             # errors echo the client's id too, so pipelined WebSocket
@@ -155,10 +170,11 @@ class AlignServer:
             self._last_failed_shards = tuple(result.failed_shards)
         else:
             self._last_failed_shards = ()
-        payload = {"result": result.to_dict()}
-        if req.id is not None:
-            payload["id"] = req.id
-        return 200, ok_response(payload)
+        with self._span("serve.respond"):
+            payload = {"result": result.to_dict()}
+            if req.id is not None:
+                payload["id"] = req.id
+            return 200, ok_response(payload)
 
     async def handle_add(self, body) -> tuple[int, bytes]:
         try:

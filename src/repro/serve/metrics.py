@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import threading
 
+from ..core.spans import NAMES as SPAN_NAMES
+
 _NBUCKETS = 64
 _FIRST_EDGE_S = 1e-5        # 10 µs; edges double per bucket → ~58 s cap
 
@@ -92,8 +94,10 @@ class ServeMetrics:
         # adds acknowledged per durable flush — how well group commit is
         # amortizing fsyncs (mean ~1 means per-record fsync cost)
         self.wal_group_commit = Histogram(first_edge=1.0)
-        self.stage_seconds = {"sketch": 0.0, "probe": 0.0, "sweep": 0.0,
-                              "queue_wait": 0.0}
+        # every engine and front-end span (repro.core.spans), present
+        # from the start so a delta over any window exists
+        self.stage_seconds = dict.fromkeys(SPAN_NAMES + ("queue_wait",),
+                                           0.0)
 
     def inc(self, name: str, by: int = 1) -> None:
         with self._lock:
@@ -101,15 +105,24 @@ class ServeMetrics:
 
     def observe_batch(self, size: int, queue_waits, stage: dict) -> None:
         """One dispatched batch: its occupancy, each member's queue wait,
-        and the engine's per-stage seconds for the ``find_batch`` call."""
+        and the engine's seconds per span for the ``find_batch`` call
+        (every key its ``stage`` dict carries)."""
         with self._lock:
             self.counters["batches_total"] += 1
             self.batch_size.add(float(size))
             for w in queue_waits:
                 self.queue_wait.add(w)
                 self.stage_seconds["queue_wait"] += w
-            for key in ("sketch", "probe", "sweep"):
-                self.stage_seconds[key] += stage.get(key, 0.0)
+            for key, seconds in stage.items():
+                self.stage_seconds[key] = \
+                    self.stage_seconds.get(key, 0.0) + seconds
+
+    def observe_span(self, name: str, seconds: float) -> None:
+        """Seconds of one span outside the engine's batches (the front
+        end's ``serve.parse``/``serve.respond``, on the event loop)."""
+        with self._lock:
+            self.stage_seconds[name] = \
+                self.stage_seconds.get(name, 0.0) + seconds
 
     def observe_latency(self, seconds: float) -> None:
         with self._lock:
