@@ -48,8 +48,11 @@ benchmark's fused-pipeline row, plus work counters: ``batches``
 the probe gathered, the grouping's input), ``groups_kept`` ((query,
 text) groups with at least ⌈kθ⌉ distinct coordinates),
 ``host_large_groups`` (kept groups of more than ``_SMALL_GROUP_MAX``
-windows, which the device plan sweeps on the host by design) and
-``host_large_windows`` (the windows of those groups).
+windows, which the device plan sweeps on the host by design),
+``host_large_windows`` (the windows of those groups) and
+``host_large_rejected`` (those of them that the exact test in front of
+the host sweep, ``query._large_groups_hot``, finds can hold no cell
+covered ⌈kθ⌉ times, so they are not swept).
 """
 
 from __future__ import annotations
@@ -75,7 +78,8 @@ _I32_MAX = np.iinfo(np.int32).max
 _STATS = {"arena_uploads": 0, "arena_bytes": 0,
           "h2d_bytes": 0, "d2h_bytes": 0, "batches": 0,
           "sweep_launches": 0, "probe_windows": 0, "groups_kept": 0,
-          "host_large_groups": 0, "host_large_windows": 0}
+          "host_large_groups": 0, "host_large_windows": 0,
+          "host_large_rejected": 0}
 
 
 def transfer_stats() -> dict:
@@ -282,15 +286,20 @@ def fused_batch_query(index, sketches, B: int, m: int, *,
         da = device_arena(index)
         starts, ends = _device_probe(da, pkeys, coords, valid, stage_times)
         with span(stage_times, "probe.gather"):
+            # int32 (row ids fit, since the arena went resident): half
+            # the bytes of each per-window array; one past 32 MiB is
+            # mapped fresh, a page fault per page, on every batch
             counts = ends - starts
-            row_ids = _concat_ranges(starts, counts)
-            probe_ids = np.repeat(np.arange(len(pkeys), dtype=np.int64),
+            first = (starts - np.cumsum(counts) + counts).astype(np.int32)
+            row_ids = np.repeat(first, counts) + \
+                np.arange(int(counts.sum()), dtype=np.int32)
+            probe_ids = np.repeat(np.arange(len(pkeys), dtype=np.int32),
                                   counts)
             qid_all, cid_all = probe_ids // k, probe_ids % k
             # the ONE window column the host touches: text ids, for
             # grouping and result labelling (mmap page-ins, not bus
             # traffic)
-            tid_all = np.asarray(arena.windows[row_ids, 0], np.int64)
+            tid_all = np.asarray(arena.windows[row_ids, 0])
     with span(stage_times, "sweep"):
         if not len(row_ids):
             return [[] for _ in range(B)]
@@ -308,7 +317,7 @@ def _fused_sweep(arena, da: DeviceArena, row_ids, qid_all, tid_all,
     from ..kernels.sweep_grid import sweep_grid
     from .query import (_SIZE_BUCKETS, _SMALL_GROUP_MAX, _emit,
                         _extract_runs, _group_bounds, _pad_groups,
-                        _sweep_text)
+                        _sweep_large)
     with span(times, "sweep.group"):
         order, g_starts, g_ends, distinct = _group_bounds(
             qid_all, tid_all, cid_all)
@@ -342,18 +351,17 @@ def _fused_sweep(arena, da: DeviceArena, row_ids, qid_all, tid_all,
 
     blocks: dict[int, list] = {}
     with span(times, "sweep.large"):
-        read = 0.0
-        for g in large.tolist():
-            # large group: host sweep straight off the mmap rows
-            t = time.perf_counter()
-            rows = np.asarray(arena.windows[row_s[g_starts[g]:g_ends[g]],
-                                            1:5], np.int64)
-            read += time.perf_counter() - t
-            blocks[g] = _sweep_text(rows, m)
-        add_seconds(times, "sweep.large.read", read)
+        # large groups: host sweep straight off the mmap rows, read once
+        t = time.perf_counter()
+        at = order[_concat_ranges(g_starts[large], sizes[large])]
+        rect = np.asarray(arena.windows[row_ids[at], 1:5])
+        add_seconds(times, "sweep.large.read", time.perf_counter() - t)
+        rejected = _sweep_large(large, rect, cid_all[at], sizes, m, blocks,
+                                times)
     add_counts(probe_windows=len(row_ids), groups_kept=len(kept),
                host_large_groups=len(large),
-               host_large_windows=sizes[large].sum())
+               host_large_windows=sizes[large].sum(),
+               host_large_rejected=rejected)
 
     with span(times, "sweep.emit"):
         for ids, hot_np, xs_np, ys_np in grids:

@@ -23,6 +23,7 @@ Two execution paths over the same algorithm:
 from __future__ import annotations
 
 import math
+import time
 from collections import defaultdict
 from dataclasses import dataclass
 
@@ -31,7 +32,7 @@ import numpy as np
 from .frozen import _concat_ranges
 from .plan import resolve_plan
 from .results import UNSET, QueryOptions, coerce_query_options
-from .spans import span
+from .spans import add_seconds, span
 
 
 @dataclass
@@ -242,6 +243,123 @@ def _extract_runs(hot: np.ndarray, xs: np.ndarray, ys: np.ndarray
     return out
 
 
+_POS = 32                # bits of a position (a window bound, or one past)
+_MASK = (1 << _POS) - 1
+_SEG_MAX = 1 << 29      # (group, coordinate) ids that keep the keys in 63
+
+
+def _coverage(lo: np.ndarray, hi: np.ndarray, seg: np.ndarray, per: int
+              ) -> tuple[np.ndarray, np.ndarray]:
+    """How many segments' unions of half-open intervals ``[lo, hi)``
+    cover each position of their group (``seg // per``), as the sorted
+    event keys ``(group << 32) | position`` and the count after each."""
+    base = seg << _POS
+    start, end = base | lo, base | hi
+    if (start[1:] < start[:-1]).any():
+        o = np.argsort(start, kind="stable")     # adaptive to sorted runs
+        start, end = start[o], end[o]
+    # pieces of each segment's union: an interval starts a new piece
+    # where it begins past the furthest end before it in its segment
+    reach = np.maximum.accumulate(end)
+    first = np.flatnonzero(np.concatenate(
+        [[True], start[1:] > reach[:-1]]))
+    last = np.append(first[1:] - 1, len(start) - 1)
+
+    def grouped(key):
+        return ((key >> _POS) // per << _POS) | (key & _MASK)
+
+    # ends (low bit 0) sort before starts at one position
+    ev = np.sort(np.concatenate([grouped(reach[last]) << 1,
+                                 grouped(start[first]) << 1 | 1]))
+    return ev >> 1, np.cumsum((ev & 1) * 2 - 1)
+
+
+def _hot_groups(cover: tuple[np.ndarray, np.ndarray], G: int, m: int
+                ) -> np.ndarray:
+    """bool (G,): the groups with a position ``_coverage`` counts >= m
+    times."""
+    pos, depth = cover
+    out = np.zeros(G, bool)
+    out[pos[depth >= m] >> _POS] = True
+    return out
+
+
+def _meets_hot(lo: np.ndarray, hi: np.ndarray, grp: np.ndarray,
+               cover: tuple[np.ndarray, np.ndarray], m: int) -> np.ndarray:
+    """Whether each interval ``[lo, hi)`` of group ``grp`` meets a
+    position that ``_coverage`` counts at least m times."""
+    pos, depth = cover
+    hot = depth >= m
+    # measure of the hot set before each event; a group's last event is
+    # an end at count 0, so nothing hot spans two groups
+    before = np.concatenate([[0], np.cumsum(
+        np.where(hot[:-1], np.diff(pos), 0))])
+
+    def hot_below(x):
+        i = np.maximum(np.searchsorted(pos, x, side="right") - 1, 0)
+        return before[i] + hot[i] * np.maximum(x - pos[i], 0)
+
+    base = grp << _POS
+    return hot_below(base | hi) > hot_below(base | lo)
+
+
+def _large_groups_hot(rect: np.ndarray, cid: np.ndarray, sizes: np.ndarray,
+                      m: int) -> np.ndarray:
+    """Which of G groups may hold a cell covered by >= m of its
+    rectangles: a bool (G,) mask that is False only where
+    ``_sweep_text`` of the group returns ``[]``.
+
+    rect: int (N, 4) rows (a, b, c, d) of the G groups back to back
+    (``sizes[g]`` rows each), cid (N,) the sketch coordinate of each row.
+    One coordinate's windows in one text are disjoint (a cell has one
+    min-hash per coordinate), so a cell covered >= m times lies in the
+    windows of >= m distinct coordinates.  Its row i then lies in the
+    union of the x-projections ``[a, b+1)`` of >= m coordinates, its
+    column j in the union of the y-projections ``[c, d+1)`` of >= m
+    coordinates, and every rectangle covering it meets both hot sets.  A
+    group whose rectangles meeting both hot sets span fewer than m
+    coordinates holds no such cell.  The rectangles that meet both still
+    hold every rectangle covering a hot cell, so the test repeats on them
+    until it drops none.  Sorts and ``searchsorted`` over the rows, no
+    grid.
+    """
+    G = len(sizes)
+    if G == 0:
+        return np.zeros(0, bool)
+    per = int(cid.max()) + 1
+    if G * per > _SEG_MAX:
+        cut = _SEG_MAX // per
+        n = int(sizes[:cut].sum())
+        return np.concatenate([
+            _large_groups_hot(rect[:n], cid[:n], sizes[:cut], m),
+            _large_groups_hot(rect[n:], cid[n:], sizes[cut:], m)])
+    grp = np.repeat(np.arange(G, dtype=np.int64), sizes)
+    a, b, c, d = (rect[:, i].astype(np.int64) for i in range(4))
+    # (group, segment, a, b, c, d) of the rows still in play
+    rows = (grp, grp * per + cid, a, b, c, d)
+
+    def take(mask):
+        at = np.flatnonzero(mask)
+        return tuple(v[at] for v in rows)
+
+    while True:
+        n = len(rows[0])
+        x = _coverage(rows[2], rows[3] + 1, rows[1], per)
+        rows = take(_hot_groups(x, G, m)[rows[0]])
+        if not len(rows[0]):
+            return np.zeros(G, bool)
+        y = _coverage(rows[4], rows[5] + 1, rows[1], per)
+        rows = take(_hot_groups(y, G, m)[rows[0]])
+        grp, seg, a, b, c, d = rows
+        meets = _meets_hot(a, b + 1, grp, x, m) & \
+            _meets_hot(c, d + 1, grp, y, m)
+        coords = np.bincount(seg[meets], minlength=G * per)
+        keep = (coords.reshape(G, per) > 0).sum(axis=1) >= m
+        rows = take(meets & keep[grp])
+        if len(rows[0]) in (0, n):
+            return keep
+
+
 def _gather_coord(index, i: int, probe_keys: list
                   ) -> tuple[np.ndarray, np.ndarray]:
     """All windows colliding with the B probe keys on coordinate ``i``:
@@ -434,6 +552,23 @@ def _emit(kept: np.ndarray, blocks: dict, starts: np.ndarray,
     return results
 
 
+def _sweep_large(large: np.ndarray, rect: np.ndarray, cid: np.ndarray,
+                 sizes: np.ndarray, m: int, blocks: dict,
+                 times: dict | None) -> int:
+    """``blocks[g]`` for the large groups: ``[]`` for those
+    ``_large_groups_hot`` rejects (timed as ``sweep.large.filter``),
+    ``_sweep_text`` of all the rows of the rest.  rect and cid hold the
+    groups' rows back to back.  Returns the number rejected."""
+    t = time.perf_counter()
+    keep = _large_groups_hot(rect, cid, sizes[large], m)
+    add_seconds(times, "sweep.large.filter", time.perf_counter() - t)
+    ends = np.cumsum(sizes[large]).tolist()
+    for g, kept, hi, n in zip(large.tolist(), keep.tolist(), ends,
+                              sizes[large].tolist()):
+        blocks[g] = _sweep_text(rect[hi - n:hi], m) if kept else []
+    return len(large) - int(keep.sum())
+
+
 def _sweep_gathered(gathered, B: int, m: int, sweep: str,
                     times: dict | None = None) -> list[list[Alignment]]:
     """Group the gathered windows by (query, text) and plane-sweep each
@@ -470,13 +605,15 @@ def _sweep_gathered(gathered, B: int, m: int, sweep: str,
                               _sweep_small_batch(arr, sizes[ids], m)))
 
     with span(times, "sweep.large"):
-        for g in large.tolist():
-            blocks[g] = _sweep_text(win_all[starts[g]:ends[g], 1:5], m)
+        at = _concat_ranges(starts[large], sizes[large])
+        rejected = _sweep_large(large, win_all[at, 1:5], cid_all[order[at]],
+                                sizes, m, blocks, times)
     if sweep == "device":
         from .device_plan import add_counts
         add_counts(sweep_launches=len(grids), probe_windows=len(qid_all),
                    groups_kept=len(kept), host_large_groups=len(large),
-                   host_large_windows=sizes[large].sum())
+                   host_large_windows=sizes[large].sum(),
+                   host_large_rejected=rejected)
 
     with span(times, "sweep.emit"):
         for ids, hot, xs, ys in grids:

@@ -22,6 +22,8 @@ The span names are the ``stage_times`` keys the engine fills (and
                    the read-back of the coverage grids
 ``sweep.large``    the host sweep of the groups the grouped sweep leaves
 ``sweep.large.read`` (seconds only) the mmap row reads of those groups
+``sweep.large.filter`` (seconds only) the test that rejects those groups
+                   that cannot hold a cell covered >= m times
 ``sweep.emit``     run extraction and building the ``Alignment``s
 ``results``        building the ``QueryResult``s (``Aligner.find_batch``)
 ``serve.parse``    the server's request parse and tokenisation
@@ -42,7 +44,7 @@ __all__ = ["NAMES", "add_seconds", "span"]
 
 NAMES = ("sketch", "probe", "probe.device", "probe.gather",
          "sweep", "sweep.group", "sweep.device", "sweep.large",
-         "sweep.large.read", "sweep.emit", "results",
+         "sweep.large.read", "sweep.large.filter", "sweep.emit", "results",
          "serve.parse", "serve.respond")
 
 

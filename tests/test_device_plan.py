@@ -316,10 +316,34 @@ def test_auto_plan_lets_backend_errors_through(monkeypatch):
         resolve_plan(QueryOptions(plan="auto"))
 
 
+def test_fused_path_gathers_in_int32(monkeypatch):
+    # the per-window arrays of a batch are half the bytes of int64 ones:
+    # past 32 MiB the allocator maps each afresh on every batch
+    seen = {}
+    real = dp._fused_sweep
+
+    def spy(arena, da, row_ids, qid_all, tid_all, cid_all, *rest):
+        seen.update(row=row_ids.dtype, qid=qid_all.dtype,
+                    tid=tid_all.dtype, cid=cid_all.dtype)
+        return real(arena, da, row_ids, qid_all, tid_all, cid_all, *rest)
+
+    monkeypatch.setattr(dp, "_fused_sweep", spy)
+    rng = np.random.default_rng(0)
+    docs = _corpus(rng)
+    frozen = _frozen("multiset", docs)
+    qs = _queries(rng, docs)
+    got = batch_query(frozen, qs, 0.5, options=QueryOptions(plan="device"))
+    assert seen == dict.fromkeys(("row", "qid", "tid", "cid"),
+                                 np.dtype(np.int32))
+    assert _batch_blocks(got) == _batch_blocks(
+        batch_query(frozen, qs, 0.5, options=QueryOptions(plan="cpu")))
+
+
 @pytest.mark.parametrize("live", [False, True])
 def test_device_plan_counts_probes_and_host_swept_groups(live, tmp_path):
+    from repro.core.frozen import _concat_ranges
     from repro.core.query import (_SMALL_GROUP_MAX, _group_bounds,
-                                  batch_probe)
+                                  _large_groups_hot, batch_probe)
     rng = np.random.default_rng(10)
     docs = _corpus(rng, n=120)
     frozen = _frozen("multiset", docs)
@@ -330,10 +354,15 @@ def test_device_plan_counts_probes_and_host_swept_groups(live, tmp_path):
     qs = [d[:100].copy() for d in docs[:4]] + _queries(rng, docs)
     m = int(np.ceil(8 * 0.5))
     q, w, c = batch_probe(frozen, frozen.scheme.sketch_batch(qs))
-    _, g_lo, g_hi, distinct = _group_bounds(q, w[:, 0], c)
+    order, g_lo, g_hi, distinct = _group_bounds(q, w[:, 0], c)
     kept = distinct >= m
-    large = int((kept & (g_hi - g_lo > _SMALL_GROUP_MAX)).sum())
+    is_large = kept & (g_hi - g_lo > _SMALL_GROUP_MAX)
+    large = int(is_large.sum())
     assert large and (kept & (g_hi - g_lo <= _SMALL_GROUP_MAX)).any()
+    # the cpu plan's grouping through the test in front of the host sweep
+    sizes = (g_hi - g_lo)[is_large]
+    at = order[_concat_ranges(g_lo[is_large], sizes)]
+    rejected = int((~_large_groups_hot(w[at, 1:5], c[at], sizes, m)).sum())
     reset_transfer_stats()
     opts = QueryOptions(plan="device")
     got = (index.batch_query(qs, 0.5, options=opts) if live
@@ -341,6 +370,8 @@ def test_device_plan_counts_probes_and_host_swept_groups(live, tmp_path):
     st = transfer_stats()
     assert st["batches"] == 1                     # one resident-arena probe
     assert st["host_large_groups"] == large
+    assert 0 <= st["host_large_rejected"] <= st["host_large_groups"]
+    assert st["host_large_rejected"] == rejected
     assert st["sweep_launches"] >= 1
     assert _batch_blocks(got) == _batch_blocks(
         batch_query(frozen, qs, 0.5, options=QueryOptions(plan="cpu")))
