@@ -45,8 +45,9 @@ flow, uncounted copies aside) for the residency tests and the roofline
 benchmark's fused-pipeline row, plus work counters: ``batches``
 (probes of a resident arena, one per batch and frozen level),
 ``sweep_launches`` (device sweep launches), ``probe_windows`` (windows
-the probe gathered, the grouping's input), ``groups_kept`` ((query,
-text) groups with at least ⌈kθ⌉ distinct coordinates),
+the probe hit, the sum of its extents), ``probe_text_runs`` (the text
+runs of those extents, the fused grouping's input), ``groups_kept``
+((query, text) groups with at least ⌈kθ⌉ distinct coordinates),
 ``host_large_groups`` (kept groups of more than ``_SMALL_GROUP_MAX``
 windows, which the device plan sweeps on the host by design),
 ``host_large_windows`` (the windows of those groups) and
@@ -77,7 +78,8 @@ _I32_MAX = np.iinfo(np.int32).max
 # count device probes, device sweep launches and the grouping's work.
 _STATS = {"arena_uploads": 0, "arena_bytes": 0,
           "h2d_bytes": 0, "d2h_bytes": 0, "batches": 0,
-          "sweep_launches": 0, "probe_windows": 0, "groups_kept": 0,
+          "sweep_launches": 0, "probe_windows": 0, "probe_text_runs": 0,
+          "groups_kept": 0,
           "host_large_groups": 0, "host_large_windows": 0,
           "host_large_rejected": 0}
 
@@ -110,10 +112,12 @@ class DeviceArena:
     Keys are split into u32 (hi, lo) halves plus the coordinate tag word
     (the probe's comparison format); offsets are narrowed to int32
     (guarded at build — an arena too large raises ``DeviceArenaError``);
-    ``win_rect`` holds only the (a, b, c, d) rectangle columns, because
-    the text-id column is read host-side (an mmap column read) for
-    grouping and never needs the bus.  An empty arena is resident with
-    ``n == 0`` and answers every probe with a miss.
+    ``win_rect`` holds only the (a, b, c, d) rectangle columns.  The
+    text-id column never needs the bus: the host keeps it as a run table,
+    ``run_start``/``run_tid``, the maximal stretches of one text id
+    inside one slot's extent (every extent bound is a run bound), which
+    the fused path groups by.  An empty arena is resident with ``n == 0``
+    and answers every probe with a miss.
     """
 
     mode: str
@@ -124,6 +128,23 @@ class DeviceArena:
     offsets: object           # jnp i32 (n + 1,)
     win_rect: object          # jnp i32 (nwin, 4)
     nbytes: int
+    run_start: np.ndarray     # host i32 (nruns + 1,) first row of each run
+    run_tid: np.ndarray       # host i32 (nruns,) text id of each run
+
+
+def _text_runs(tid: np.ndarray, offsets: np.ndarray
+               ) -> tuple[np.ndarray, np.ndarray]:
+    """The run table of a text-id column cut into CSR extents: the
+    int32 bounds of every maximal stretch of one text id inside one
+    extent, and each stretch's text id."""
+    nwin = len(tid)
+    bound = np.empty(nwin, bool)
+    bound[:1] = True
+    np.not_equal(tid[1:], tid[:-1], out=bound[1:])
+    cuts = offsets[:-1]
+    bound[cuts[cuts < nwin]] = True
+    run_start = np.append(np.flatnonzero(bound), nwin).astype(np.int32)
+    return run_start, tid[run_start[:-1]]
 
 
 def _build_device_arena(arena) -> DeviceArena:
@@ -145,13 +166,17 @@ def _build_device_arena(arena) -> DeviceArena:
     else:
         ktag = np.ascontiguousarray(arena.coords, np.uint32)
     offsets = np.asarray(arena.offsets, np.int32)
-    rect = np.ascontiguousarray(np.asarray(arena.windows)[:, 1:5], np.int32)
+    windows = np.asarray(arena.windows)
+    rect = np.ascontiguousarray(windows[:, 1:5], np.int32)
+    run_start, run_tid = _text_runs(
+        np.ascontiguousarray(windows[:, 0], np.int32), offsets)
     dev = DeviceArena(
         mode=arena.mode, n=n,
         khi=jnp.asarray(khi), klo=jnp.asarray(klo), ktag=jnp.asarray(ktag),
         offsets=jnp.asarray(offsets), win_rect=jnp.asarray(rect),
         nbytes=(khi.nbytes + klo.nbytes + ktag.nbytes + offsets.nbytes +
-                rect.nbytes))
+                rect.nbytes),
+        run_start=run_start, run_tid=run_tid)
     _STATS["arena_uploads"] += 1
     _STATS["arena_bytes"] += dev.nbytes
     return dev
@@ -272,8 +297,9 @@ def resident_probe(index, pkeys, coords, valid
 def fused_batch_query(index, sketches, B: int, m: int, *,
                       stage_times: dict | None = None) -> list:
     """The fused frozen-index batch path: device probe over the resident
-    arena, host grouping on the windows' text-id column alone (an mmap
-    column read — no transfer), device gather of the rectangle rows from
+    arena, host grouping over the text runs of the probe's extents (the
+    resident arena's run table, no per-window read), expansion of the
+    kept groups' rows alone, device gather of their rectangle rows from
     the resident ``win_rect``, device sweep, and block extraction from
     the compressed coverage grids.  Block-identical to the cpu plan.
     ``stage_times`` accumulates the ``probe`` and ``sweep`` spans and
@@ -286,53 +312,97 @@ def fused_batch_query(index, sketches, B: int, m: int, *,
         da = device_arena(index)
         starts, ends = _device_probe(da, pkeys, coords, valid, stage_times)
         with span(stage_times, "probe.gather"):
-            # int32 (row ids fit, since the arena went resident): half
-            # the bytes of each per-window array; one past 32 MiB is
-            # mapped fresh, a page fault per page, on every batch
-            counts = ends - starts
-            first = (starts - np.cumsum(counts) + counts).astype(np.int32)
-            row_ids = np.repeat(first, counts) + \
-                np.arange(int(counts.sum()), dtype=np.int32)
-            probe_ids = np.repeat(np.arange(len(pkeys), dtype=np.int32),
-                                  counts)
-            qid_all, cid_all = probe_ids // k, probe_ids % k
-            # the ONE window column the host touches: text ids, for
-            # grouping and result labelling (mmap page-ins, not bus
-            # traffic)
-            tid_all = np.asarray(arena.windows[row_ids, 0])
+            probe_r, run_r = _probe_runs(da.run_start, starts, ends)
+            add_counts(probe_windows=(ends - starts).sum(),
+                       probe_text_runs=len(run_r))
     with span(stage_times, "sweep"):
-        if not len(row_ids):
-            return [[] for _ in range(B)]
-        return _fused_sweep(arena, da, row_ids, qid_all, tid_all, cid_all,
-                            B, m, stage_times)
+        with span(stage_times, "sweep.group"):
+            groups = _kept_groups(da, probe_r, run_r, k, m)
+        return _fused_sweep(arena, da, *groups, B, m, stage_times)
 
 
-def _fused_sweep(arena, da: DeviceArena, row_ids, qid_all, tid_all,
-                 cid_all, B: int, m: int, times: dict | None) -> list:
-    """The sweep stage of the fused path: group, sweep the small groups
-    on the device and the large ones on the host straight off the mmap
-    rows, then emit."""
+def _probe_runs(run_start: np.ndarray, starts: np.ndarray, ends: np.ndarray
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """The text runs of each probe's extent ``[starts, ends)``, in gather
+    order (probe, then row): int32 (probe ids, run ids).  Extent bounds
+    are run bounds, so one ``searchsorted`` per bound finds them."""
+    dt = run_start.dtype   # mixed dtypes would copy the table per batch
+    lo = np.searchsorted(run_start, starts.astype(dt))
+    n = np.searchsorted(run_start, ends.astype(dt)) - lo
+    probe_r = np.repeat(np.arange(len(starts), dtype=np.int32), n)
+    return probe_r, _ranges32(lo, n)
+
+
+def _ranges32(lo: np.ndarray, n: np.ndarray) -> np.ndarray:
+    """``_concat_ranges`` in int32: the ranges ``[lo, lo + n)`` back to
+    back.  Half the bytes of an int64 index; one past 32 MiB is mapped
+    fresh, a page fault per page, on every batch."""
+    first = (lo - np.cumsum(n) + n).astype(np.int32)
+    return np.repeat(first, n) + np.arange(int(n.sum()), dtype=np.int32)
+
+
+def _kept_groups(da: DeviceArena, probe_r: np.ndarray, run_r: np.ndarray,
+                 k: int, m: int) -> tuple:
+    """(query, text) grouping over text runs, and the rows of the groups
+    with at least m distinct coordinates.
+
+    A stable sort of the runs by (query id, text id) keeps gather order
+    inside each group, which is coordinate order (probe ids are
+    query-major) and then row order, so expanding the kept runs gives
+    each group's rows in the order of ``query._group_bounds``'s stable
+    row sort.  A group's distinct coordinates are its coordinate steps,
+    counted over runs; one text may lie in several runs of one extent.
+    Returns ``(rows, cids, sizes, qids, tids, distinct)``: int32 row ids
+    and coordinate ids of the kept groups back to back, and per kept
+    group (in (query, text) order) its row count, query, text and
+    distinct coordinates.
+    """
+    qid, cid = probe_r // k, probe_r % k
+    tid = da.run_tid[run_r]
+    key = qid.astype(np.int64) << 32 | tid
+    # timsort merges the k text-ascending runs of each query
+    order = np.argsort(key, kind="stable")
+    key, cid, run_r = key[order], cid[order], run_r[order]
+    n = len(key)
+    step = np.ones(n, bool)
+    np.not_equal(key[1:], key[:-1], out=step[1:])
+    g_lo = np.flatnonzero(step)
+    np.not_equal(cid[1:], cid[:-1], out=step[1:])
+    step[g_lo] = True
+    distinct = np.add.reduceat(step, g_lo)
+    kept = np.flatnonzero(distinct >= m)
+    g_n = np.diff(np.append(g_lo, n))[kept]
+    at = _ranges32(g_lo[kept], g_n)
+    runs, cid = run_r[at], cid[at]
+    lo = da.run_start[runs]
+    ln = da.run_start[runs + 1] - lo
+    sizes = np.add.reduceat(ln.astype(np.int64), np.cumsum(g_n) - g_n)
+    head = key[g_lo[kept]]
+    return (_ranges32(lo, ln), np.repeat(cid, ln), sizes,
+            head >> 32, head & 0xFFFFFFFF, distinct[kept])
+
+
+def _fused_sweep(arena, da: DeviceArena, rows, cids, sizes, qids, tids,
+                 distinct, B: int, m: int, times: dict | None) -> list:
+    """The sweep stage of the fused path over the kept groups' rows
+    (``_kept_groups``): sweep the small groups on the device and the
+    large ones on the host straight off the mmap rows, then emit."""
     import jax.numpy as jnp
 
     from ..kernels.sweep_grid import sweep_grid
     from .query import (_SIZE_BUCKETS, _SMALL_GROUP_MAX, _emit,
-                        _extract_runs, _group_bounds, _pad_groups,
-                        _sweep_large)
-    with span(times, "sweep.group"):
-        order, g_starts, g_ends, distinct = _group_bounds(
-            qid_all, tid_all, cid_all)
-        qid_s, tid_s, row_s = qid_all[order], tid_all[order], row_ids[order]
-        sizes = g_ends - g_starts
-        kept = np.flatnonzero(distinct >= m)
-        is_small = sizes[kept] <= _SMALL_GROUP_MAX
-        small, large = kept[is_small], kept[~is_small]
+                        _extract_runs, _pad_groups, _sweep_large)
+    kept = np.arange(len(sizes))
+    g_starts = np.cumsum(sizes) - sizes
+    is_small = sizes <= _SMALL_GROUP_MAX
+    small, large = kept[is_small], kept[~is_small]
 
     grids = []                  # (group ids, hot, xs, ys) per size bucket
     for b_lo, b_hi in _SIZE_BUCKETS:
         ids = small[(sizes[small] > b_lo) & (sizes[small] <= b_hi)]
         if not len(ids):
             continue
-        idx = _pad_groups(row_s, g_starts[ids], sizes[ids]).astype(np.int32)
+        idx = _pad_groups(rows, g_starts[ids], sizes[ids]).astype(np.int32)
         sz32 = sizes[ids].astype(np.int32)
         with span(times, "sweep.device"):
             # device-side row gather from the resident rectangle columns:
@@ -353,13 +423,12 @@ def _fused_sweep(arena, da: DeviceArena, row_ids, qid_all, tid_all,
     with span(times, "sweep.large"):
         # large groups: host sweep straight off the mmap rows, read once
         t = time.perf_counter()
-        at = order[_concat_ranges(g_starts[large], sizes[large])]
-        rect = np.asarray(arena.windows[row_ids[at], 1:5])
+        at = _concat_ranges(g_starts[large], sizes[large])
+        rect = np.asarray(arena.windows[rows[at], 1:5])
         add_seconds(times, "sweep.large.read", time.perf_counter() - t)
-        rejected = _sweep_large(large, rect, cid_all[at], sizes, m, blocks,
+        rejected = _sweep_large(large, rect, cids[at], sizes, m, blocks,
                                 times)
-    add_counts(probe_windows=len(row_ids), groups_kept=len(kept),
-               host_large_groups=len(large),
+    add_counts(groups_kept=len(kept), host_large_groups=len(large),
                host_large_windows=sizes[large].sum(),
                host_large_rejected=rejected)
 
@@ -367,4 +436,4 @@ def _fused_sweep(arena, da: DeviceArena, row_ids, qid_all, tid_all,
         for ids, hot_np, xs_np, ys_np in grids:
             blocks.update(zip(ids.tolist(),
                               _extract_runs(hot_np, xs_np, ys_np)))
-        return _emit(kept, blocks, g_starts, qid_s, tid_s, distinct, B)
+        return _emit(kept, blocks, kept, qids, tids, distinct, B)

@@ -322,10 +322,9 @@ def test_fused_path_gathers_in_int32(monkeypatch):
     seen = {}
     real = dp._fused_sweep
 
-    def spy(arena, da, row_ids, qid_all, tid_all, cid_all, *rest):
-        seen.update(row=row_ids.dtype, qid=qid_all.dtype,
-                    tid=tid_all.dtype, cid=cid_all.dtype)
-        return real(arena, da, row_ids, qid_all, tid_all, cid_all, *rest)
+    def spy(arena, da, rows, cids, *rest):
+        seen.update(row=rows.dtype, cid=cids.dtype)
+        return real(arena, da, rows, cids, *rest)
 
     monkeypatch.setattr(dp, "_fused_sweep", spy)
     rng = np.random.default_rng(0)
@@ -333,8 +332,7 @@ def test_fused_path_gathers_in_int32(monkeypatch):
     frozen = _frozen("multiset", docs)
     qs = _queries(rng, docs)
     got = batch_query(frozen, qs, 0.5, options=QueryOptions(plan="device"))
-    assert seen == dict.fromkeys(("row", "qid", "tid", "cid"),
-                                 np.dtype(np.int32))
+    assert seen == dict.fromkeys(("row", "cid"), np.dtype(np.int32))
     assert _batch_blocks(got) == _batch_blocks(
         batch_query(frozen, qs, 0.5, options=QueryOptions(plan="cpu")))
 
