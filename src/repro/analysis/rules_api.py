@@ -8,7 +8,7 @@ The old spellings still work through shims that emit
 so the shims can eventually be deleted:
 
 * **RPR401** — legacy per-call query kwargs (``backend=``,
-  ``probe_backend=``, ``sweep=``, ``fanout=``, ``sketches=``) on
+  ``fanout=``, ``sketches=``) on
   ``find``/``find_batch``/``batch_query`` method calls; pass
   ``options=QueryOptions(...)``.
 * **RPR402** — any call using ``legacy_tuples=``; consume
@@ -17,7 +17,7 @@ so the shims can eventually be deleted:
   (``src/repro/core/index.py``); use ``IndexBuilder`` (mutable) or
   ``SearchIndex`` (frozen).
 * **RPR404** — per-stage backend kwargs (``sketch_backend=``,
-  ``probe_backend=``, ``sweep=``, ``sketches=``) on *any* call to
+  ``sketches=``) on *any* call to
   ``query``/``batch_query``/``find``/``find_batch``, bare functions
   included.  The PR 10 execution-plan redesign folded these into
   ``QueryOptions``; pass ``options=QueryOptions(plan=..., ...)``.
@@ -52,11 +52,9 @@ RPR404 = ("RPR404",
 SHIM_FILE = "src/repro/core/index.py"
 
 _QUERY_METHODS = frozenset({"find", "find_batch", "batch_query"})
-_LEGACY_KWARGS = frozenset({"backend", "probe_backend", "sweep", "fanout",
-                            "sketches"})
+_LEGACY_KWARGS = frozenset({"backend", "fanout", "sketches"})
 _QUERY_CALLS = frozenset({"query", "batch_query", "find", "find_batch"})
-_STAGE_KWARGS = frozenset({"sketch_backend", "probe_backend", "sweep",
-                           "sketches"})
+_STAGE_KWARGS = frozenset({"sketch_backend", "sketches"})
 
 
 @checker(RPR401, RPR402, RPR403, RPR404)

@@ -245,8 +245,7 @@ class Aligner:
 
     def find_batch(self, texts, theta: float, *,
                    options: QueryOptions | None = None,
-                   backend=UNSET, sketch_backend=UNSET, probe_backend=UNSET,
-                   sweep=UNSET,
+                   backend=UNSET, sketch_backend=UNSET,
                    legacy_tuples: bool = False,
                    stage_times: dict | None = None) -> list[QueryResult]:
         """Batched :meth:`find` (the serving path — one fused arena probe
@@ -257,17 +256,14 @@ class Aligner:
         default), ``"device"`` (the arena stays resident on the
         accelerator; probe and sweep run there, block-identical
         to cpu), or ``"auto"`` (device when a real accelerator backs jax,
-        else silently cpu).  Stage fields on the options object pin
-        individual stages for debugging — e.g.
-        ``QueryOptions(sketch_backend="pallas")`` moves weighted-scheme
-        sketching into the fused device kernel, and
-        ``probe_backend="percoord"`` forces the legacy k-probe loop.
+        else silently cpu).  ``QueryOptions(sketch_backend="pallas")``
+        moves weighted-scheme sketching into the fused device kernel.
         Sharded indexes fan the probes out across a thread pool
         (``QueryOptions.fanout``).
 
-        The pre-redesign ``backend``/``sketch_backend``/``probe_backend``/
-        ``sweep`` keywords still work for one release behind a
-        ``DeprecationWarning`` (they coerce to pins on the cpu plan), as
+        The pre-redesign ``backend``/``sketch_backend`` keywords still
+        work for one release behind a ``DeprecationWarning`` (they coerce
+        to pins on the cpu plan), as
         does ``legacy_tuples=True`` for the old ``list[list[Alignment]]``
         return shape.  ``stage_times`` accumulates per-stage wall seconds
         under ``"sketch"``/``"probe"``/``"sweep"``, their children and
@@ -275,8 +271,7 @@ class Aligner:
         serve-path metrics hook)."""
         opts = coerce_query_options(options, "Aligner.find_batch",
                                     backend=backend,
-                                    sketch_backend=sketch_backend,
-                                    probe_backend=probe_backend, sweep=sweep)
+                                    sketch_backend=sketch_backend)
         tokens = [self._tokens(t) for t in texts]
         failed: list[int] = []
         if isinstance(self._index, ShardedAlignmentIndex):

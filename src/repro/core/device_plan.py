@@ -1,13 +1,14 @@
-"""The device-resident query pipeline behind ``plan="device"``.
+"""The device-resident half of ``plan="device"``.
 
-The cpu plan's batch flow round-trips the host between every stage: the
-arena binary search (even with ``probe_backend="pallas"``) re-uploads the
-key arena each launch, the collided window rows are gathered on the host,
-and the grouped sweep is NumPy.  This module keeps the heavy state — the
-fused :class:`~repro.core.frozen.ProbeArena` key/offset/window arrays —
-*resident* on the accelerator and runs the probe binary search (a jitted
-XLA program over the HBM-resident arrays) and the grouped small-group
-sweep (a Pallas kernel) on the device, so per batch only
+The cpu plan probes a frozen level with one host ``searchsorted`` of its
+fused arena and gathers every hit row on the host.  This module keeps the
+heavy state — the fused :class:`~repro.core.frozen.ProbeArena`
+key/offset/window arrays — *resident* on the accelerator and runs the
+probe binary search (a jitted XLA program over the HBM-resident arrays)
+there; the probe's extents are then grouped over the text runs of the
+arena's run table (:class:`ResidentProbe`), and the small groups' rows
+are gathered on the device for the grouped sweep (a Pallas kernel, run
+from ``repro.core.query.sweep_groups``).  So per batch only
 
 * up:   the packed probe keys (B*k few-byte words) and the small-group
   gather index grids,
@@ -26,9 +27,8 @@ so the upload happens at most once per store generation and invalidation
 is automatic.  An arena the device probe cannot address (a CSR extent past
 int32) raises :class:`DeviceArenaError`: the device plan never quietly
 serves a batch on the host.  The mutable live delta level never comes
-through here — it keeps the host dict probe
-(``repro.core.query.batch_probe`` routes non-frozen levels to the
-per-coordinate loop), which is what keeps live serving correct between
+through here — ``repro.core.query._probe_level`` gives mutable levels the
+host dict probe, which is what keeps live serving correct between
 compactions.
 
 Bit parity
@@ -42,14 +42,14 @@ bit-identical to ``plan="cpu"`` — gated in ``tests/test_device_plan.py``.
 ``transfer_stats()`` exposes logical host<->device byte counters (what
 crosses the bus on a real accelerator; in interpret mode the same arrays
 flow, uncounted copies aside) for the residency tests and the roofline
-benchmark's fused-pipeline row, plus work counters: ``batches``
-(probes of a resident arena, one per batch and frozen level),
-``sweep_launches`` (device sweep launches), ``probe_windows`` (windows
-the probe hit, the sum of its extents), ``probe_text_runs`` (the text
-runs of those extents, the fused grouping's input), ``groups_kept``
-((query, text) groups with at least ⌈kθ⌉ distinct coordinates),
-``host_large_groups`` (kept groups of more than ``_SMALL_GROUP_MAX``
-windows, which the device plan sweeps on the host by design),
+benchmark's fused-pipeline row, plus work counters: ``batches`` (probes
+of a resident arena, one per batch and frozen level) and, counted by
+``query.sweep_groups`` on either plan, ``sweep_launches`` (device sweep
+launches), ``probe_windows`` (windows the probe hit, the sum of its
+extents), ``probe_text_runs`` (the text runs of those extents, the run
+grouping's input), ``groups_kept`` ((query, text) groups with at least
+⌈kθ⌉ distinct coordinates), ``host_large_groups`` (kept groups of more
+than ``_SMALL_GROUP_MAX`` windows, which are swept on the host by design),
 ``host_large_windows`` (the windows of those groups) and
 ``host_large_rejected`` (those of them that the exact test in front of
 the host sweep, ``query._large_groups_hot``, finds can hold no cell
@@ -58,16 +58,16 @@ covered ⌈kθ⌉ times, so they are not swept).
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 
 import numpy as np
 
-from .frozen import MODE_PACKED, PACK_SHIFT, _concat_ranges
-from .spans import add_seconds, span
+from .frozen import MODE_PACKED, PACK_SHIFT
+from .query import KeptGroups
+from .spans import span
 
-__all__ = ["DeviceArena", "DeviceArenaError", "device_arena",
-           "resident_probe", "fused_batch_query", "transfer_stats",
+__all__ = ["DeviceArena", "DeviceArenaError", "ResidentProbe",
+           "device_arena", "resident_probe", "transfer_stats",
            "reset_transfer_stats"]
 
 _I32_MAX = np.iinfo(np.int32).max
@@ -95,8 +95,8 @@ def reset_transfer_stats() -> None:
 
 
 def add_counts(**counts: int) -> None:
-    """Add to the module's counters (the device sweep's callers report
-    ``sweep_launches`` and the grouping's counts through here)."""
+    """Add to the module's counters (``query.sweep_groups`` reports the
+    sweep's bytes and the work counters through here)."""
     for key, n in counts.items():
         _STATS[key] += int(n)
 
@@ -116,8 +116,12 @@ class DeviceArena:
     text-id column never needs the bus: the host keeps it as a run table,
     ``run_start``/``run_tid``, the maximal stretches of one text id
     inside one slot's extent (every extent bound is a run bound), which
-    the fused path groups by.  An empty arena is resident with ``n == 0``
-    and answers every probe with a miss.
+    the run grouping groups by.  An empty arena is resident with
+    ``n == 0`` and answers every probe with a miss.
+
+    It is also the row source of its level's :class:`~repro.core.query.
+    KeptGroups`: the host reads rows off ``windows``, the arena's mmap,
+    and the device gathers them from ``win_rect``.
     """
 
     mode: str
@@ -130,6 +134,20 @@ class DeviceArena:
     nbytes: int
     run_start: np.ndarray     # host i32 (nruns + 1,) first row of each run
     run_tid: np.ndarray       # host i32 (nruns,) text id of each run
+    windows: np.ndarray       # host (nwin, 5) window rows (the mmap)
+
+    reads_mmap = True
+
+    def read(self, rows: np.ndarray) -> np.ndarray:
+        """The (a, b, c, d) rectangles of the given rows, off the mmap."""
+        return np.asarray(self.windows[rows, 1:5])
+
+    def device_rects(self, idx: np.ndarray) -> tuple[object, int]:
+        """The rectangles of an index grid, gathered on the device from
+        the resident columns (only the grid goes up); and its bytes."""
+        import jax.numpy as jnp
+        idx = np.asarray(idx, np.int32)
+        return jnp.take(self.win_rect, jnp.asarray(idx), axis=0), idx.nbytes
 
 
 def _text_runs(tid: np.ndarray, offsets: np.ndarray
@@ -176,7 +194,7 @@ def _build_device_arena(arena) -> DeviceArena:
         offsets=jnp.asarray(offsets), win_rect=jnp.asarray(rect),
         nbytes=(khi.nbytes + klo.nbytes + ktag.nbytes + offsets.nbytes +
                 rect.nbytes),
-        run_start=run_start, run_tid=run_tid)
+        run_start=run_start, run_tid=run_tid, windows=arena.windows)
     _STATS["arena_uploads"] += 1
     _STATS["arena_bytes"] += dev.nbytes
     return dev
@@ -202,7 +220,7 @@ def device_arena(index) -> DeviceArena:
 
 
 # --------------------------------------------------------------------------
-# resident probe (the probe stage of both the pinned and the fused paths)
+# resident probe
 # --------------------------------------------------------------------------
 
 
@@ -290,35 +308,33 @@ def resident_probe(index, pkeys, coords, valid
 
 
 # --------------------------------------------------------------------------
-# fused pipeline (probe="device" AND sweep="device": no host window gather)
+# a frozen level under the device plan: resident probe, run grouping
 # --------------------------------------------------------------------------
 
 
-def fused_batch_query(index, sketches, B: int, m: int, *,
-                      stage_times: dict | None = None) -> list:
-    """The fused frozen-index batch path: device probe over the resident
-    arena, host grouping over the text runs of the probe's extents (the
-    resident arena's run table, no per-window read), expansion of the
-    kept groups' rows alone, device gather of their rectangle rows from
-    the resident ``win_rect``, device sweep, and block extraction from
-    the compressed coverage grids.  Block-identical to the cpu plan.
-    ``stage_times`` accumulates the ``probe`` and ``sweep`` spans and
-    their children (:mod:`repro.core.spans`).
-    """
-    with span(stage_times, "probe"):
+class ResidentProbe:
+    """A frozen level probed on its resident arena (the device-plan choice
+    of ``query._probe_level``): the device probe's CSR extents, then
+    (``gather``) the text runs of those extents from the arena's run
+    table, then (``kept``) the run grouping.  No window row is read on
+    the host until the sweep reads the large groups'."""
+
+    def __init__(self, index, sketches, times: dict | None = None):
         arena = index.arena()
-        k = arena.k
+        self.k = arena.k
         pkeys, coords, valid = arena.encode_batch(sketches)
-        da = device_arena(index)
-        starts, ends = _device_probe(da, pkeys, coords, valid, stage_times)
-        with span(stage_times, "probe.gather"):
-            probe_r, run_r = _probe_runs(da.run_start, starts, ends)
-            add_counts(probe_windows=(ends - starts).sum(),
-                       probe_text_runs=len(run_r))
-    with span(stage_times, "sweep"):
-        with span(stage_times, "sweep.group"):
-            groups = _kept_groups(da, probe_r, run_r, k, m)
-        return _fused_sweep(arena, da, *groups, B, m, stage_times)
+        self.da = device_arena(index)
+        self.starts, self.ends = _device_probe(self.da, pkeys, coords, valid,
+                                               times)
+
+    def gather(self, times: dict | None) -> None:
+        with span(times, "probe.gather"):
+            self.runs = _probe_runs(self.da.run_start, self.starts,
+                                    self.ends)
+
+    def kept(self, m: int) -> KeptGroups:
+        return _kept_groups(self.da, *self.runs, self.k, m,
+                            hits=int((self.ends - self.starts).sum()))
 
 
 def _probe_runs(run_start: np.ndarray, starts: np.ndarray, ends: np.ndarray
@@ -342,20 +358,18 @@ def _ranges32(lo: np.ndarray, n: np.ndarray) -> np.ndarray:
 
 
 def _kept_groups(da: DeviceArena, probe_r: np.ndarray, run_r: np.ndarray,
-                 k: int, m: int) -> tuple:
-    """(query, text) grouping over text runs, and the rows of the groups
-    with at least m distinct coordinates.
+                 k: int, m: int, *, hits: int) -> KeptGroups:
+    """The run grouping: (query, text) grouping over text runs, and the
+    rows of the groups with at least m distinct coordinates.
 
     A stable sort of the runs by (query id, text id) keeps gather order
     inside each group, which is coordinate order (probe ids are
     query-major) and then row order, so expanding the kept runs gives
-    each group's rows in the order of ``query._group_bounds``'s stable
-    row sort.  A group's distinct coordinates are its coordinate steps,
+    each group's rows in the order of ``query._row_groups``'s stable row
+    sort.  A group's distinct coordinates are its coordinate steps,
     counted over runs; one text may lie in several runs of one extent.
-    Returns ``(rows, cids, sizes, qids, tids, distinct)``: int32 row ids
-    and coordinate ids of the kept groups back to back, and per kept
-    group (in (query, text) order) its row count, query, text and
-    distinct coordinates.
+    Returns the kept groups with int32 row ids and coordinate ids, and
+    ``da`` as their row source; ``hits`` is the windows the probe hit.
     """
     qid, cid = probe_r // k, probe_r % k
     tid = da.run_tid[run_r]
@@ -378,62 +392,7 @@ def _kept_groups(da: DeviceArena, probe_r: np.ndarray, run_r: np.ndarray,
     ln = da.run_start[runs + 1] - lo
     sizes = np.add.reduceat(ln.astype(np.int64), np.cumsum(g_n) - g_n)
     head = key[g_lo[kept]]
-    return (_ranges32(lo, ln), np.repeat(cid, ln), sizes,
-            head >> 32, head & 0xFFFFFFFF, distinct[kept])
-
-
-def _fused_sweep(arena, da: DeviceArena, rows, cids, sizes, qids, tids,
-                 distinct, B: int, m: int, times: dict | None) -> list:
-    """The sweep stage of the fused path over the kept groups' rows
-    (``_kept_groups``): sweep the small groups on the device and the
-    large ones on the host straight off the mmap rows, then emit."""
-    import jax.numpy as jnp
-
-    from ..kernels.sweep_grid import sweep_grid
-    from .query import (_SIZE_BUCKETS, _SMALL_GROUP_MAX, _emit,
-                        _extract_runs, _pad_groups, _sweep_large)
-    kept = np.arange(len(sizes))
-    g_starts = np.cumsum(sizes) - sizes
-    is_small = sizes <= _SMALL_GROUP_MAX
-    small, large = kept[is_small], kept[~is_small]
-
-    grids = []                  # (group ids, hot, xs, ys) per size bucket
-    for b_lo, b_hi in _SIZE_BUCKETS:
-        ids = small[(sizes[small] > b_lo) & (sizes[small] <= b_hi)]
-        if not len(ids):
-            continue
-        idx = _pad_groups(rows, g_starts[ids], sizes[ids]).astype(np.int32)
-        sz32 = sizes[ids].astype(np.int32)
-        with span(times, "sweep.device"):
-            # device-side row gather from the resident rectangle columns:
-            # only the (G, S) index grid goes up, never the window rows
-            rects = jnp.take(da.win_rect, jnp.asarray(idx), axis=0)
-            hot, xs, ys = sweep_grid(rects, jnp.asarray(sz32), m=m)
-            NX = int(xs.shape[1])
-            # bool-cast on device: the grid crosses at 1 byte per cell
-            hot_np = np.asarray(hot[:, :NX - 1, :NX - 1].astype(jnp.bool_))
-            xs_np = np.asarray(xs, np.int64)
-            ys_np = np.asarray(ys, np.int64)
-        _STATS["sweep_launches"] += 1
-        _STATS["h2d_bytes"] += idx.nbytes + sz32.nbytes
-        _STATS["d2h_bytes"] += hot_np.size + 2 * xs_np.size * 4  # b8/i32
-        grids.append((ids, hot_np, xs_np, ys_np))
-
-    blocks: dict[int, list] = {}
-    with span(times, "sweep.large"):
-        # large groups: host sweep straight off the mmap rows, read once
-        t = time.perf_counter()
-        at = _concat_ranges(g_starts[large], sizes[large])
-        rect = np.asarray(arena.windows[rows[at], 1:5])
-        add_seconds(times, "sweep.large.read", time.perf_counter() - t)
-        rejected = _sweep_large(large, rect, cids[at], sizes, m, blocks,
-                                times)
-    add_counts(groups_kept=len(kept), host_large_groups=len(large),
-               host_large_windows=sizes[large].sum(),
-               host_large_rejected=rejected)
-
-    with span(times, "sweep.emit"):
-        for ids, hot_np, xs_np, ys_np in grids:
-            blocks.update(zip(ids.tolist(),
-                              _extract_runs(hot_np, xs_np, ys_np)))
-        return _emit(kept, blocks, kept, qids, tids, distinct, B)
+    return KeptGroups(rows=_ranges32(lo, ln), cids=np.repeat(cid, ln),
+                      sizes=sizes, qids=head >> 32, tids=head & 0xFFFFFFFF,
+                      distinct=distinct[kept], source=da, hits=hits,
+                      runs=len(run_r))

@@ -474,13 +474,10 @@ class ProbeArena:
         return pkeys, coords, valid
 
     def probe(self, pkeys: np.ndarray, coords: np.ndarray,
-              valid: np.ndarray, *, backend: str = "numpy"
-              ) -> tuple[np.ndarray, np.ndarray]:
+              valid: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         """Vectorized arena lookup -> CSR (starts, ends) int64, one
-        ``searchsorted`` for the whole batch (``backend="pallas"``: one
-        device binary search, :mod:`repro.kernels.probe_arena`, uploading
-        the arena per call).  Misses get an empty range (start == end ==
-        0)."""
+        ``searchsorted`` for the whole batch.  Misses get an empty range
+        (start == end == 0)."""
         n = len(self.keys)
         if n == 0 or len(pkeys) == 0:
             z = np.zeros(len(pkeys), np.int64)
@@ -488,43 +485,26 @@ class ProbeArena:
         if self.mode == MODE_PACKED:
             q = (coords.astype(np.uint64) << np.uint64(PACK_SHIFT)) | \
                 np.where(valid, pkeys, 0)
-            if backend == "pallas":
-                pos = self._device_search(q, np.zeros(len(q), np.uint32))
-            else:
-                pos = np.searchsorted(self.keys, q)
+            pos = np.searchsorted(self.keys, q)
             safe = np.minimum(pos, n - 1)
             hit = valid & (pos < n) & (self.keys[safe] == q)
         else:
-            if backend == "pallas":
-                pos = self._device_search(pkeys, coords.astype(np.uint32))
-            else:
-                pos = np.searchsorted(self.keys, pkeys)
-                # advance over the (tiny) duplicate run to the probe's
-                # coordinate; bounded by the longest equal-key run
-                for _ in range(self.max_run - 1):
-                    safe = np.minimum(pos, n - 1)
-                    adv = (pos < n) & (self.keys[safe] == pkeys) & \
-                        (self.coords[safe] < coords)
-                    if not adv.any():
-                        break
-                    pos = pos + adv
+            pos = np.searchsorted(self.keys, pkeys)
+            # advance over the (tiny) duplicate run to the probe's
+            # coordinate; bounded by the longest equal-key run
+            for _ in range(self.max_run - 1):
+                safe = np.minimum(pos, n - 1)
+                adv = (pos < n) & (self.keys[safe] == pkeys) & \
+                    (self.coords[safe] < coords)
+                if not adv.any():
+                    break
+                pos = pos + adv
             safe = np.minimum(pos, n - 1)
             hit = valid & (pos < n) & (self.keys[safe] == pkeys) & \
                 (self.coords[safe] == coords)
         starts = np.where(hit, self.offsets[safe], 0)
         ends = np.where(hit, self.offsets[safe + 1], 0)
         return starts, ends
-
-    def _device_search(self, qkeys: np.ndarray, qtags: np.ndarray
-                       ) -> np.ndarray:
-        from ..kernels.probe_arena import arena_search
-        if self.mode == MODE_COORD:
-            tags = np.ascontiguousarray(self.coords, dtype=np.uint32)
-        else:
-            tags = np.zeros(len(self.keys), np.uint32)
-        return np.asarray(arena_search(
-            np.asarray(self.keys), tags, qkeys, qtags),
-            dtype=np.int64)
 
     # -- introspection ------------------------------------------------------
 

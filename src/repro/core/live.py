@@ -13,12 +13,13 @@ already exist:
 * ``delta``  — a small mutable :class:`~repro.core.builder.IndexBuilder`
   that absorbs ``add_text`` writes between compactions.
 
-Queries merge deterministically: one arena probe over the frozen index,
-one dict probe over the delta, delta text ids re-based after the frozen
-corpus, and ONE shared plane-sweep over the union — block-identical to a
-from-scratch build of the same corpus (every text id belongs to exactly
-one side, so each (query, text) sweep group comes entirely from one probe
-and keeps its coordinate-ascending order).  Results are remapped to
+Queries merge deterministically: the batch pipeline
+(``repro.core.query.run_batch``) probes each level (an arena probe of the
+frozen index, a dict probe of the delta) and sweeps it, delta text ids
+re-based after the frozen corpus.  That is block-identical to a
+from-scratch build of the same corpus: every text id belongs to exactly
+one level, so each (query, text) sweep group comes entirely from one
+level and keeps its coordinate-ascending order.  Results are remapped to
 *global* doc ids through ``doc_map`` (the store manifest's mapping,
 extended by live adds), so sharded serving keeps one id space.
 
@@ -39,7 +40,6 @@ on disk for rollback, and readers flip via
 
 from __future__ import annotations
 
-import math
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -50,12 +50,9 @@ from ..wal import WalConfig, WriteAheadLog, wal_dir
 from . import store as index_store
 from .builder import IndexBuilder
 from .guard import engine_only
-from .plan import resolve_plan
-from .query import (Alignment, _sweep_gathered, batch_probe as _batch_probe,
-                    query as _query)
+from .query import Alignment, query as _query, run_batch
 from .results import UNSET, QueryOptions, coerce_query_options
 from .search import SearchIndex
-from .spans import span
 
 
 @dataclass
@@ -172,7 +169,7 @@ class LiveIndex:
 
     @property
     def is_live(self) -> bool:
-        return True             # query.batch_probe dispatches on this
+        return True             # query.probe_store dispatches on this
 
     def _levels(self):
         """The index levels in local-id order (frozen, sealed?, delta)."""
@@ -298,36 +295,17 @@ class LiveIndex:
             base += lv.num_texts
         return rows
 
-    def batch_probe(self, sketches, *, probe_backend: str = "numpy"
-                    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """The live probe stage: one arena probe of the frozen index plus
-        one dict probe per non-empty delta level, level tids re-based into
-        the local id order — a single gathered (query ids, windows,
-        coordinate ids) triple for the shared sweep.
-
-        Empty levels are skipped before probing: a freshly opened live
-        store (zero delta tables) pays exactly the frozen arena probe and
-        nothing else.
+    def query_levels(self) -> list[tuple[object, int]]:
+        """The non-empty levels, in local-id order, each with the local id
+        of its first text: what the batch pipeline probes.  A freshly
+        opened live store (an empty delta) probes its frozen level alone.
         """
-        chunks = []
-        base = 0
+        out, base = [], 0
         for lv in self._levels():
             if lv.num_texts:
-                q, w, c = _batch_probe(lv, sketches,
-                                       probe_backend=probe_backend)
-                if len(q):
-                    if base:
-                        w = w.copy()
-                        w[:, 0] += base
-                    chunks.append((q, w, c))
+                out.append((lv, base))
             base += lv.num_texts
-        if not chunks:
-            return (np.empty(0, np.int64), np.empty((0, 5), np.int64),
-                    np.empty(0, np.int64))
-        if len(chunks) == 1:
-            return chunks[0]
-        return tuple(np.concatenate(parts)
-                     for parts in zip(*chunks))
+        return out
 
     def query(self, tokens, theta: float) -> list[Alignment]:
         """Definition-1 alignment over frozen + deltas, in global doc ids."""
@@ -338,45 +316,26 @@ class LiveIndex:
 
     def batch_query(self, texts, theta: float, *,
                     options: QueryOptions | None = None,
-                    sketches=UNSET, backend=UNSET, probe_backend=UNSET,
-                    sweep=UNSET,
+                    sketches=UNSET, backend=UNSET,
                     stage_times: dict | None = None) -> list[list[Alignment]]:
-        """Batched :meth:`query` (the serving path): sketch once, merge the
-        frozen and delta probes, sweep the union, remap to global ids.
+        """Batched :meth:`query` (the serving path): the batch pipeline
+        (:func:`repro.core.query.run_batch`) over the levels, text ids
+        remapped to global doc ids through ``doc_map``.
 
         Execution comes in as ``options=QueryOptions(...)``; the ``plan``
         field is resolved once per batch (:func:`repro.core.plan.
         resolve_plan`).  Under ``plan="device"`` the frozen level probes
         the device-resident arena while the mutable delta level keeps the
-        host dict probe (live writes stay served without re-upload churn),
-        and the merged union sweeps on-device.  The pre-redesign
-        ``sketches``/``backend``/``probe_backend``/``sweep`` keywords
-        still work behind a ``DeprecationWarning``.  ``stage_times``
-        accumulates per-stage wall seconds under
-        ``"sketch"``/``"probe"``/``"sweep"`` and their children
-        (:mod:`repro.core.spans`) when given.
+        host dict probe (live writes stay served without re-upload churn).
+        The pre-redesign ``sketches``/``backend`` keywords still work
+        behind a ``DeprecationWarning``.  ``stage_times`` accumulates
+        per-stage wall seconds under ``"sketch"``/``"probe"``/``"sweep"``
+        and their children (:mod:`repro.core.spans`) when given.
         """
         opts = coerce_query_options(options, "LiveIndex.batch_query",
-                                    sketches=sketches, backend=backend,
-                                    probe_backend=probe_backend, sweep=sweep)
-        xp = resolve_plan(opts)
-        if not len(texts):
-            return []
-        with span(stage_times, "sketch"):
-            sk = opts.sketches
-            if sk is None:
-                sk = self.scheme.sketch_batch(texts,
-                                              backend=xp.sketch_backend)
-            m = max(1, math.ceil(self.scheme.k * theta))
-        with span(stage_times, "probe"):
-            gathered = self.batch_probe(sk, probe_backend=xp.probe_backend)
-        with span(stage_times, "sweep"):
-            return [sorted((Alignment(text_id=self.doc_map[al.text_id],
-                                      blocks=al.blocks, ncoords=al.ncoords)
-                            for al in res),
-                           key=lambda a: a.text_id)
-                    for res in _sweep_gathered(gathered, len(texts), m,
-                                               xp.sweep, stage_times)]
+                                    sketches=sketches, backend=backend)
+        return run_batch(self, texts, theta, opts, stage_times,
+                         ids=self.doc_map.__getitem__)
 
     # -- compaction ---------------------------------------------------------
 

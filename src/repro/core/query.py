@@ -6,18 +6,15 @@ Two execution paths over the same algorithm:
 
 * ``query``       — one query at a time; works on mutable (dict) and frozen
   indexes alike.
-* ``batch_query`` — the serving path: sketches the whole batch at once,
-  probes ALL B*k (query, coordinate) pairs against the fused probe arena
-  (``repro.core.frozen.ProbeArena``) in ONE ``searchsorted`` + gather
-  (``probe_backend="numpy"``; ``"pallas"`` routes the binary search through
-  the device search, ``"percoord"`` keeps the legacy per-coordinate probe
-  loop, which is also what mutable dict indexes use), and groups the
-  collided windows by (query, text) with one lexsort.  The per-group plane
-  sweep goes through a grouped dispatcher: the many tiny groups of Zipf
-  traffic are batched through one vectorized small-group sweep
-  (``sweep="grouped"``, the default) and only large groups fall back to
-  the per-group ``_sweep_text``.  Every combination returns block-for-block
-  the same results as looping ``query``.
+* ``batch_query`` — the serving path, one pipeline for every store kind
+  (:func:`run_batch`): sketch the whole batch at once, probe each store
+  level as :func:`_probe_level` chooses from the plan and the level's
+  kind, group the collided windows by (query, text) into
+  :class:`KeptGroups`, and sweep them with :func:`sweep_groups`: the many
+  tiny groups of Zipf traffic a size bucket at a time through one
+  vectorized (or device) small-group sweep, the large groups through an
+  exact test and the per-group ``_sweep_text``.  Every plan returns
+  block-for-block the same results as looping ``query``.
 """
 
 from __future__ import annotations
@@ -26,6 +23,7 @@ import math
 import time
 from collections import defaultdict
 from dataclasses import dataclass
+from functools import partial
 
 import numpy as np
 
@@ -360,135 +358,85 @@ def _large_groups_hot(rect: np.ndarray, cid: np.ndarray, sizes: np.ndarray,
             return keep
 
 
-def _gather_coord(index, i: int, probe_keys: list
-                  ) -> tuple[np.ndarray, np.ndarray]:
-    """All windows colliding with the B probe keys on coordinate ``i``:
-    (query ids (M,), windows (M, 5) int64)."""
-    if index.is_frozen:
-        table = index.frozen[i]
-        packed = table.encode(probe_keys)
-        starts, ends = table.probe(packed)
-        counts = ends - starts
-        qids = np.repeat(np.arange(len(probe_keys), dtype=np.int64), counts)
-        rows = table.windows[_concat_ranges(starts, counts)]
-        return qids, rows.astype(np.int64)
-    qid_chunks, win_chunks = [], []
-    for b, key in enumerate(probe_keys):
-        wins = index.tables[i].get(key)
-        if wins:
-            qid_chunks.append(np.full(len(wins), b, np.int64))
-            win_chunks.append(np.asarray(wins, np.int64))
-    if not qid_chunks:
-        return np.empty(0, np.int64), np.empty((0, 5), np.int64)
-    return np.concatenate(qid_chunks), np.concatenate(win_chunks)
-
-
-def _gather_arena(index, sketches, probe_backend: str
-                  ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """One-shot probe of ALL B*k coordinates against the fused arena:
-    (query ids (M,), windows (M, 5) int64, coordinate ids (M,))."""
-    arena = index.arena()
-    k = arena.k
-    pkeys, coords, valid = arena.encode_batch(sketches)
-    if probe_backend == "device":
-        from .device_plan import resident_probe
-        starts, ends = resident_probe(index, pkeys, coords, valid)
-    else:
-        starts, ends = arena.probe(
-            pkeys, coords, valid,
-            backend="pallas" if probe_backend == "pallas" else "numpy")
-    counts = ends - starts
-    rows = arena.windows[_concat_ranges(starts, counts)]
-    probe_ids = np.repeat(np.arange(len(pkeys), dtype=np.int64), counts)
-    return probe_ids // k, rows.astype(np.int64), probe_ids % k
-
-
-def batch_query(index, queries, theta: float, *,
-                options: QueryOptions | None = None,
-                sketches=UNSET,
-                sketch_backend=UNSET,
-                probe_backend=UNSET,
-                sweep=UNSET,
-                stage_times: dict | None = None) -> list[list[Alignment]]:
-    """Definition-1 alignment for a batch of queries (the serving path).
-
-    Execution comes in as ``options=QueryOptions(...)``: the ``plan``
-    field picks the pipeline (``"cpu"`` — exact host sketch, one host
-    ``searchsorted`` over the fused arena, vectorized grouped sweep;
-    ``"device"`` — arena resident on the accelerator, probe binary search
-    and small-group sweep on the device, fused so only probe inputs go
-    up and final block extents come down; ``"auto"`` — device when a real
-    accelerator backs jax, else cpu), resolved ONCE per batch by
-    :func:`repro.core.plan.resolve_plan`.  Stage fields on the options
-    object pin individual stages for debugging.  All plans and pins are
-    block-identical.
-
-    ``QueryOptions.sketches`` short-circuits sketching when the caller
-    already holds the batch's sketch coordinates (the sharded fan-out
-    computes them once and reuses them on every shard).
-
-    The bare ``sketches=``/``sketch_backend=``/``probe_backend=``/
-    ``sweep=`` keywords are deprecated (one release behind a
-    ``DeprecationWarning``); they coerce to pins on the cpu plan.
-
-    ``stage_times``, when given, accumulates per-stage wall seconds under
-    the keys ``"sketch"``, ``"probe"`` and ``"sweep"`` and their children
-    (the names of :mod:`repro.core.spans`; the serve-path metrics hook;
-    += so one dict can span many batches).
-    """
-    opts = coerce_query_options(options, "batch_query", sketches=sketches,
-                                sketch_backend=sketch_backend,
-                                probe_backend=probe_backend, sweep=sweep)
-    xp = resolve_plan(opts)
-    B = len(queries)
-    if B == 0:
-        return []
-    m = max(1, math.ceil(index.scheme.k * theta))
-    with span(stage_times, "sketch"):
-        sk = opts.sketches
-        if sk is None:
-            sk = index.scheme.sketch_batch(queries,
-                                           backend=xp.sketch_backend)
-    if xp.fused and getattr(index, "is_frozen", False):
-        from .device_plan import fused_batch_query
-        return fused_batch_query(index, sk, B, m, stage_times=stage_times)
-    with span(stage_times, "probe"):
-        gathered = batch_probe(index, sk, probe_backend=xp.probe_backend)
-    with span(stage_times, "sweep"):
-        return _sweep_gathered(gathered, B, m, xp.sweep, stage_times)
-
-
-def batch_probe(index, sketches, *, probe_backend: str = "numpy"
+def batch_probe(index, sketches
                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """The probe stage of ``batch_query``: all windows colliding with the
+    """The host probe of one store level: all windows colliding with the
     batch's sketches, as (query ids (M,), windows (M, 5) int64, coordinate
-    ids (M,)).
-
-    Pure NumPy/mmap work that releases the GIL in searchsorted/gather —
-    the sharded fan-out overlaps THIS stage across shards with a thread
-    pool and keeps the (GIL-bound) sweep stage serial.
+    ids (M,)).  A frozen level probes its fused arena in ONE
+    ``searchsorted`` and one gather (NumPy/mmap work that releases the
+    GIL, which the sharded fan-out overlaps); a mutable level probes its
+    dict tables coordinate by coordinate.
     """
-    if getattr(index, "is_live", False):
-        # live index: merge the frozen-arena and delta-dict probes (delta
-        # tids re-based after the frozen corpus) into one gathered triple
-        return index.batch_probe(sketches, probe_backend=probe_backend)
-    B = len(sketches)
-    k = index.scheme.k
-    if index.is_frozen and probe_backend != "percoord":
-        return _gather_arena(index, sketches, probe_backend)
-    qid_chunks, win_chunks, cid_chunks = [], [], []
-    for i in range(k):
-        qids, wins = _gather_coord(index, i, [sketches[b][i]
-                                              for b in range(B)])
-        if len(qids):
-            qid_chunks.append(qids)
-            win_chunks.append(wins)
-            cid_chunks.append(np.full(len(qids), i, np.int64))
-    if not qid_chunks:
-        return (np.empty(0, np.int64), np.empty((0, 5), np.int64),
-                np.empty(0, np.int64))
-    return (np.concatenate(qid_chunks), np.concatenate(win_chunks),
-            np.concatenate(cid_chunks))
+    if index.is_frozen:
+        arena = index.arena()
+        k = arena.k
+        pkeys, coords, valid = arena.encode_batch(sketches)
+        starts, ends = arena.probe(pkeys, coords, valid)
+        counts = ends - starts
+        rows = arena.windows[_concat_ranges(starts, counts)]
+        probe_ids = np.repeat(np.arange(len(pkeys), dtype=np.int64), counts)
+        return probe_ids // k, rows.astype(np.int64), probe_ids % k
+    qid, win, cid = [], [], []
+    for i in range(index.scheme.k):
+        for b, sk in enumerate(sketches):
+            wins = index.tables[i].get(sk[i])
+            if wins:
+                qid += [b] * len(wins)
+                cid += [i] * len(wins)
+                win += wins
+    return (np.array(qid, np.int64), np.array(win, np.int64).reshape(-1, 5),
+            np.array(cid, np.int64))
+
+
+# --------------------------------------------------------------------------
+# the batch pipeline: sketch, probe each store level, sweep its kept groups
+# --------------------------------------------------------------------------
+
+
+@dataclass
+class KeptGroups:
+    """The (query, text) groups of one store level with at least ⌈kθ⌉
+    distinct colliding coordinates, in (query, text) order — what both
+    groupings return: the row grouping (:func:`_row_groups`) of rows
+    gathered on the host, and the run grouping
+    (``device_plan._kept_groups``) of a level whose arena is resident.
+
+    ``rows`` and ``cids`` hold each kept group's row ids and their
+    coordinate ids back to back, coordinate-ascending within a group;
+    ``sizes``, ``qids``, ``tids`` and ``distinct`` are per group.
+    ``source`` knows where a row's rectangle lives: :class:`HostRows`, or
+    the level's ``device_plan.DeviceArena``.  ``hits`` counts the windows
+    the probe hit and ``runs`` their text runs (the run grouping's input;
+    0 for the row grouping).
+    """
+
+    rows: np.ndarray
+    cids: np.ndarray
+    sizes: np.ndarray
+    qids: np.ndarray
+    tids: np.ndarray
+    distinct: np.ndarray
+    source: object
+    hits: int
+    runs: int = 0
+
+
+class HostRows:
+    """The row source of a level gathered on the host: its (a, b, c, d)
+    rectangle rows in memory."""
+
+    reads_mmap = False
+
+    def __init__(self, rect: np.ndarray):
+        self.rect = rect
+
+    def read(self, rows: np.ndarray) -> np.ndarray:
+        return self.rect[rows]
+
+    def device_rects(self, idx: np.ndarray) -> tuple[np.ndarray, int]:
+        """The rows of the index grid, to upload; and their bytes."""
+        rect = self.rect[idx].astype(np.int32)
+        return rect, rect.nbytes
 
 
 def _group_bounds(qid_all: np.ndarray, tid_all: np.ndarray,
@@ -502,22 +450,168 @@ def _group_bounds(qid_all: np.ndarray, tid_all: np.ndarray,
     (query, text) group, which the stable sort preserves — ``starts``/
     ``ends`` bound each group in the sorted order, and ``distinct`` counts
     each group's distinct colliding sketch coordinates (the >= m
-    prefilter, one reduceat).  Shared by the host dispatcher and the fused
-    device pipeline (:mod:`repro.core.device_plan`).
+    prefilter, one reduceat).
     """
     order = np.lexsort((tid_all, qid_all))
     qid_s, tid_s, cid_s = qid_all[order], tid_all[order], cid_all[order]
     n = len(qid_s)
-    change = (qid_s[1:] != qid_s[:-1]) | (tid_s[1:] != tid_s[:-1])
-    bounds = np.flatnonzero(change) + 1
-    starts = np.concatenate([[0], bounds])
-    ends = np.concatenate([bounds, [n]])
-    cid_step = np.empty(n, bool)
-    cid_step[0] = True
-    cid_step[1:] = cid_s[1:] != cid_s[:-1]
-    cid_step[starts] = True
-    distinct = np.add.reduceat(cid_step, starts)
+    step = np.ones(n, bool)
+    step[1:] = (qid_s[1:] != qid_s[:-1]) | (tid_s[1:] != tid_s[:-1])
+    starts = np.flatnonzero(step)
+    ends = starts + np.diff(np.append(starts, n))
+    step[1:] |= cid_s[1:] != cid_s[:-1]
+    distinct = np.add.reduceat(step, starts)
     return order, starts, ends, distinct
+
+
+def _row_groups(qid: np.ndarray, win: np.ndarray, cid: np.ndarray, m: int
+                ) -> KeptGroups:
+    """The row grouping: the kept groups of windows gathered on the host
+    (``batch_probe``'s triple)."""
+    order, starts, ends, distinct = _group_bounds(qid, win[:, 0], cid)
+    sizes = ends - starts
+    kept = np.flatnonzero(distinct >= m)
+    rows = order[_concat_ranges(starts[kept], sizes[kept])]
+    head = order[starts[kept]]
+    return KeptGroups(rows=rows, cids=cid[rows], sizes=sizes[kept],
+                      qids=qid[head], tids=win[head, 0],
+                      distinct=distinct[kept], source=HostRows(win[:, 1:5]),
+                      hits=len(qid))
+
+
+class _HostProbe:
+    """A level probed on the host: the windows it hit, already gathered;
+    grouped row by row."""
+
+    def __init__(self, gathered):
+        self.gathered = gathered
+
+    def gather(self, times: dict | None) -> None:
+        pass                                 # batch_probe gathered them
+
+    def kept(self, m: int) -> KeptGroups:
+        return _row_groups(*self.gathered, m)
+
+
+def _probe_level(level, sketches, device: bool, times: dict | None):
+    """Probe one store level: the one place that chooses, from the plan
+    and the level's kind, how a level is probed and grouped.
+
+    * device plan, frozen level: the resident-arena probe, grouped over
+      the text runs of its extents (``device_plan.ResidentProbe``);
+    * cpu plan, frozen level: one host ``searchsorted`` of the arena, the
+      hit rows gathered and grouped row by row;
+    * mutable (dict) level, either plan: the per-coordinate dict probe,
+      grouped row by row.
+
+    Returns the probed level: ``gather(times)`` finishes the probe stage
+    on the calling thread, ``kept(m)`` groups it (:class:`KeptGroups`).
+    """
+    if device and level.is_frozen:
+        from .device_plan import ResidentProbe
+        return ResidentProbe(level, sketches, times)
+    return _HostProbe(batch_probe(level, sketches))
+
+
+def _shifted(ids, base: int):
+    """The text-id map of a level whose ids start at ``base`` in a store
+    whose ids ``ids`` maps (``None``: the identity)."""
+    if not base:
+        return ids
+    if ids is None:
+        return lambda t: t + base
+    return lambda t: ids(t + base)
+
+
+def probe_store(store, sketches, device: bool, times: dict | None = None,
+                ids=None) -> list:
+    """The probe stage of one store: each of its non-empty levels (one for
+    a plain index, ``LiveIndex.query_levels`` for a live one) probed
+    through :func:`_probe_level`, each paired with the map from its text
+    ids to the ids the answers carry (``ids`` maps the store's own;
+    ``None`` keeps them)."""
+    levels = (store.query_levels() if getattr(store, "is_live", False)
+              else ((store, 0),))
+    return [(_probe_level(lv, sketches, device, times), _shifted(ids, base))
+            for lv, base in levels]
+
+
+def batch_query(index, queries, theta: float, *,
+                options: QueryOptions | None = None,
+                sketches=UNSET,
+                sketch_backend=UNSET,
+                stage_times: dict | None = None) -> list[list[Alignment]]:
+    """Definition-1 alignment for a batch of queries (the serving path).
+
+    Execution comes in as ``options=QueryOptions(...)``: the ``plan``
+    field picks the pipeline (``"cpu"`` — exact host sketch, one host
+    ``searchsorted`` over the fused arena, vectorized grouped sweep;
+    ``"device"`` — arena resident on the accelerator, probe binary search
+    and small-group sweep on the device, so only probe inputs go up and
+    final block extents come down; ``"auto"`` — device when a real
+    accelerator backs jax, else cpu), resolved ONCE per batch by
+    :func:`repro.core.plan.resolve_plan`.  Both plans are block-identical.
+
+    ``QueryOptions.sketches`` short-circuits sketching when the caller
+    already holds the batch's sketch coordinates.
+
+    The bare ``sketches=``/``sketch_backend=`` keywords are deprecated
+    (one release behind a ``DeprecationWarning``).
+
+    ``stage_times``, when given, accumulates per-stage wall seconds under
+    the keys ``"sketch"``, ``"probe"`` and ``"sweep"`` and their children
+    (the names of :mod:`repro.core.spans`; the serve-path metrics hook;
+    += so one dict can span many batches).
+    """
+    opts = coerce_query_options(options, "batch_query", sketches=sketches,
+                                sketch_backend=sketch_backend)
+    return run_batch(index, queries, theta, opts, stage_times)
+
+
+def run_batch(store, queries, theta: float, opts: QueryOptions,
+              times: dict | None = None, *, ids=None,
+              fan_out=None) -> list[list[Alignment]]:
+    """The one batch pipeline behind every store kind's ``batch_query``:
+    sketch the batch once, probe every level (:func:`probe_store`), then
+    group and sweep the levels one after another (:func:`sweep_groups`).
+
+    A (query, text) group never spans two levels or two shards, so
+    sweeping level by level gives the blocks one sweep of their union
+    would.  ``ids`` maps the store's text ids to the ids the answers carry
+    (a live store's ``doc_map``).  ``fan_out``, when given, runs the probe
+    stage over the shards of a sharded store: it is called with
+    ``probe_store`` (the batch bound) and the plan's ``fanout`` and
+    returns the probed levels of every shard.  With more than one level,
+    or a map, each query's alignments are sorted by their text id.
+    """
+    xp = resolve_plan(opts)
+    B = len(queries)
+    if B == 0:
+        return []
+    m = max(1, math.ceil(store.scheme.k * theta))
+    with span(times, "sketch"):
+        sk = opts.sketches
+        if sk is None:
+            sk = store.scheme.sketch_batch(queries, backend=xp.sketch_backend)
+    with span(times, "probe"):
+        if fan_out is None:
+            levels = probe_store(store, sk, xp.device, times, ids)
+        else:
+            levels = fan_out(partial(probe_store, sketches=sk,
+                                     device=xp.device), xp.fanout)
+        for probed, _ in levels:
+            probed.gather(times)
+    with span(times, "sweep"):
+        swept = []
+        for probed, level_ids in levels:
+            with span(times, "sweep.group"):
+                kept = probed.kept(m)
+            swept.append(sweep_groups(kept, B, m, xp.device, times,
+                                      level_ids))
+        if len(levels) == 1 and levels[0][1] is None:
+            return swept[0]
+        return [sorted((al for res in swept for al in res[q]),
+                       key=lambda a: a.text_id) for q in range(B)]
 
 
 #: small-group size buckets: padded width S stays tight for the (dominant)
@@ -537,18 +631,18 @@ def _pad_groups(values: np.ndarray, starts: np.ndarray, sizes: np.ndarray
     return out
 
 
-def _emit(kept: np.ndarray, blocks: dict, starts: np.ndarray,
-          qid_s: np.ndarray, tid_s: np.ndarray, distinct: np.ndarray,
-          B: int) -> list[list[Alignment]]:
-    """Each query's alignments: the kept groups (ascending, so in text-id
-    order) whose sweep found blocks."""
+def _emit(blocks: dict, g: KeptGroups, B: int, ids
+          ) -> list[list[Alignment]]:
+    """Each query's alignments: the kept groups (in (query, text) order,
+    so each query's in text-id order) whose sweep found blocks, their text
+    ids mapped through ``ids`` when given."""
     results: list[list[Alignment]] = [[] for _ in range(B)]
-    for g in kept.tolist():
-        if blocks[g]:
-            lo = starts[g]
-            results[int(qid_s[lo])].append(
-                Alignment(text_id=int(tid_s[lo]), blocks=blocks[g],
-                          ncoords=int(distinct[g])))
+    for i, (q, t, n) in enumerate(zip(g.qids.tolist(), g.tids.tolist(),
+                                      g.distinct.tolist())):
+        if blocks[i]:
+            results[q].append(Alignment(
+                text_id=t if ids is None else ids(t), blocks=blocks[i],
+                ncoords=n))
     return results
 
 
@@ -569,57 +663,67 @@ def _sweep_large(large: np.ndarray, rect: np.ndarray, cid: np.ndarray,
     return len(large) - int(keep.sum())
 
 
-def _sweep_gathered(gathered, B: int, m: int, sweep: str,
-                    times: dict | None = None) -> list[list[Alignment]]:
-    """Group the gathered windows by (query, text) and plane-sweep each
-    group (the second stage of ``batch_query``); ``times`` accumulates
-    the ``sweep.*`` spans."""
-    qid_all, win_all, cid_all = gathered
-    if not len(qid_all):
-        return [[] for _ in range(B)]
+def sweep_groups(g: KeptGroups, B: int, m: int, device: bool,
+                 times: dict | None = None, ids=None
+                 ) -> list[list[Alignment]]:
+    """The sweep stage of every path, over one level's kept groups: each
+    query's alignments (text ids mapped through ``ids`` when given).
 
-    with span(times, "sweep.group"):
-        order, starts, ends, distinct = _group_bounds(
-            qid_all, win_all[:, 0], cid_all)
-        qid_all, win_all = qid_all[order], win_all[order]
-        sizes = ends - starts
-        kept = np.flatnonzero(distinct >= m)
-        is_small = (sizes[kept] <= _SMALL_GROUP_MAX) & \
-            (sweep in ("grouped", "device"))
-        small, large = kept[is_small], kept[~is_small]
+    Groups of at most ``_SMALL_GROUP_MAX`` windows are swept a size bucket
+    at a time: on the device plan by the device sweep
+    (``kernels.sweep_grid``, span ``sweep.device``) over rectangles the
+    row source puts on the device (a gather of the resident rectangle
+    columns, or an upload of host rows), on the cpu plan by
+    ``_sweep_small_batch``.  Larger groups are read from the row source
+    (off the mmap for a resident level, timed as ``sweep.large.read``),
+    tested by ``_large_groups_hot`` and swept on the host.  The work
+    counters of ``device_plan.transfer_stats`` are counted here.
+    """
+    from .device_plan import add_counts
+    sizes = g.sizes
+    starts = np.cumsum(sizes) - sizes
+    small = np.flatnonzero(sizes <= _SMALL_GROUP_MAX)
+    large = np.flatnonzero(sizes > _SMALL_GROUP_MAX)
 
     blocks: dict[int, list] = {}
     grids = []                  # (group ids, hot, xs, ys) per size bucket
+    up = down = 0               # device sweep bytes, up and down
     for b_lo, b_hi in _SIZE_BUCKETS:
-        ids = small[(sizes[small] > b_lo) & (sizes[small] <= b_hi)]
-        if not len(ids):
+        at = small[(sizes[small] > b_lo) & (sizes[small] <= b_hi)]
+        if not len(at):
             continue
-        arr = _pad_groups(win_all[:, 1:5], starts[ids], sizes[ids])
-        if sweep == "device":
-            from ..kernels.sweep_grid import sweep_small_batch_device
-            with span(times, "sweep.device"):
-                grids.append((ids, *sweep_small_batch_device(
-                    arr, sizes[ids], m)))
-        else:
-            blocks.update(zip(ids.tolist(),
-                              _sweep_small_batch(arr, sizes[ids], m)))
+        idx = _pad_groups(g.rows, starts[at], sizes[at])
+        if not device:
+            blocks.update(zip(at.tolist(), _sweep_small_batch(
+                g.source.read(idx), sizes[at], m)))
+            continue
+        from ..kernels.sweep_grid import sweep_small_batch_device
+        sz32 = sizes[at].astype(np.int32)
+        with span(times, "sweep.device"):
+            rects, nbytes = g.source.device_rects(idx)
+            hot, xs, ys = sweep_small_batch_device(rects, sz32, m)
+        up += nbytes + sz32.nbytes
+        down += hot.size + 2 * xs.size * 4       # b8 grid, i32 boundaries
+        grids.append((at, hot, xs, ys))
 
     with span(times, "sweep.large"):
+        t = time.perf_counter()
         at = _concat_ranges(starts[large], sizes[large])
-        rejected = _sweep_large(large, win_all[at, 1:5], cid_all[order[at]],
-                                sizes, m, blocks, times)
-    if sweep == "device":
-        from .device_plan import add_counts
-        add_counts(sweep_launches=len(grids), probe_windows=len(qid_all),
-                   groups_kept=len(kept), host_large_groups=len(large),
-                   host_large_windows=sizes[large].sum(),
-                   host_large_rejected=rejected)
+        rect = g.source.read(g.rows[at])
+        if g.source.reads_mmap:
+            add_seconds(times, "sweep.large.read", time.perf_counter() - t)
+        rejected = _sweep_large(large, rect, g.cids[at], sizes, m, blocks,
+                                times)
+    add_counts(probe_windows=g.hits, probe_text_runs=g.runs,
+               groups_kept=len(sizes), host_large_groups=len(large),
+               host_large_windows=sizes[large].sum(),
+               host_large_rejected=rejected, sweep_launches=len(grids),
+               h2d_bytes=up, d2h_bytes=down)
 
     with span(times, "sweep.emit"):
-        for ids, hot, xs, ys in grids:
-            blocks.update(zip(ids.tolist(), _extract_runs(hot, xs, ys)))
-        return _emit(kept, blocks, starts, qid_all, win_all[:, 0], distinct,
-                     B)
+        for at, hot, xs, ys in grids:
+            blocks.update(zip(at.tolist(), _extract_runs(hot, xs, ys)))
+        return _emit(blocks, g, B, ids)
 
 
 def estimate_similarity(index, query_tokens, data_tokens

@@ -17,8 +17,8 @@ facade and the network server speak in terms of:
   through ``to_dict``/``from_dict``/JSON, which is exactly the payload
   the :mod:`repro.serve` server puts on the wire.
 * :class:`QueryOptions` — one dataclass for the query-execution knobs
-  that used to sprawl across ``backend``/``probe_backend``/``sweep``/
-  ``fanout``/``sketches`` keyword arguments.  ``Aligner.find/find_batch``,
+  that used to sprawl across ``backend``/``fanout``/``sketches`` keyword
+  arguments.  ``Aligner.find/find_batch``,
   ``LiveIndex.batch_query`` and ``ShardedAlignmentIndex.batch_query`` all
   accept ``options=QueryOptions(...)``; the old kwargs still work for one
   release behind a ``DeprecationWarning`` (:func:`coerce_query_options`).
@@ -173,20 +173,18 @@ UNSET = object()
 _REMOVAL_RELEASE = "0.3"
 
 # Legacy kwargs that RENAME to a QueryOptions field.  Kwargs whose spelling
-# already matches the field (probe_backend=, sweep=, ...) live only in
-# _LEGACY_PASSTHROUGH — a name is either current or legacy, never both
-# (the old table mapped probe_backend to itself, double-listing it).
+# already matches the field (sketch_backend=, ...) live only in
+# _LEGACY_PASSTHROUGH — a name is either current or legacy, never both.
 _LEGACY_RENAMES = {"backend": "sketch_backend"}
 
 # legacy kwargs whose QueryOptions field keeps the same name
-_LEGACY_PASSTHROUGH = ("sketch_backend", "probe_backend", "sweep", "fanout",
-                      "sketches")
+_LEGACY_PASSTHROUGH = ("sketch_backend", "fanout", "sketches")
 
-#: the stage fields a plan resolves (mirrors repro.core.plan.STAGE_FIELDS,
-#: duplicated here so the wire/result layer stays import-light)
-_STAGE_FIELDS = ("sketch_backend", "probe_backend", "sweep", "fanout")
+#: the fields a plan resolves (the keys of repro.core.plan._CHOICES,
+#: repeated here so the wire/result layer stays import-light)
+_PLAN_FIELDS = ("sketch_backend", "fanout")
 
-_WIRE_FIELDS = ("plan",) + _STAGE_FIELDS
+_WIRE_FIELDS = ("plan",) + _PLAN_FIELDS
 
 
 @dataclass(frozen=True)
@@ -199,11 +197,11 @@ class QueryOptions:
         the accelerator, probe and sweep run there) or ``"auto"``
         (device when a real accelerator backs jax, else silently cpu).
         Resolved once per batch by ``repro.core.plan.resolve_plan``.
-    sketch_backend / probe_backend / sweep / fanout: per-stage *pins*.
-        ``None`` (the default) lets the plan pick; a concrete value pins
-        that one stage for debugging (``probe_backend="percoord"`` forces
-        the legacy k-probe loop regardless of plan).  Pinning a value the
-        plan cannot execute raises ``TypeError`` at resolution.
+    sketch_backend / fanout: per-stage *pins*.  ``None`` (the default)
+        lets the plan pick; ``sketch_backend="pallas"`` moves weighted
+        sketching into the f32 device kernel, ``fanout="serial"`` probes
+        a sharded store's shards one after another.  A value the plan
+        cannot execute raises ``TypeError`` at resolution.
     sketches: precomputed batch sketch coordinates, short-circuiting the
         sketch stage (the caller guarantees they match the queries).
         Excluded from the wire form.
@@ -211,8 +209,6 @@ class QueryOptions:
 
     plan: str = "cpu"
     sketch_backend: str | None = None
-    probe_backend: str | None = None
-    sweep: str | None = None
     fanout: str | None = None
     sketches: object = None
 
@@ -223,12 +219,11 @@ class QueryOptions:
         server) never coalesces into a single dispatch; unresolved pins
         (``None``) key differently from their resolved values — a
         conservative split that can only under-coalesce, never mix."""
-        return (self.plan, self.sketch_backend, self.probe_backend,
-                self.sweep, self.fanout)
+        return (self.plan, self.sketch_backend, self.fanout)
 
     def to_dict(self) -> dict:
         d = {"plan": self.plan}
-        d.update({f: getattr(self, f) for f in _STAGE_FIELDS
+        d.update({f: getattr(self, f) for f in _PLAN_FIELDS
                   if getattr(self, f) is not None})
         return d
 

@@ -32,7 +32,6 @@ across a spawn process pool) with atomic per-shard promotion.
 from __future__ import annotations
 
 import json
-import math
 import pickle
 import time
 from dataclasses import dataclass, field
@@ -44,11 +43,9 @@ from ..fault import checkpoint as fault_checkpoint
 from ..fault import fsio
 from . import store as index_store
 from .builder import IndexBuilder
-from .plan import resolve_plan
-from .query import Alignment, _sweep_gathered, batch_probe, query
+from .query import Alignment, query, run_batch
 from .results import UNSET, QueryOptions, coerce_query_options
 from .search import SearchIndex
-from .spans import span
 
 META_VERSION = 1
 
@@ -225,38 +222,33 @@ class ShardedAlignmentIndex:
 
     def batch_query(self, texts, theta: float, *,
                     options: QueryOptions | None = None,
-                    sketches=UNSET, backend=UNSET, probe_backend=UNSET,
-                    fanout=UNSET,
+                    sketches=UNSET, backend=UNSET, fanout=UNSET,
                     stage_times: dict | None = None,
                     failures: list | None = None,
                     shard_retries: int = 1,
                     retry_backoff_s: float = 0.005) -> list[list[Alignment]]:
-        """Batched fan-out: sketch the batch once (shards share the hash
-        family), probe every shard's tables with the same sketches, union
-        per query in the global id space.
+        """Batched fan-out: the batch pipeline
+        (:func:`repro.core.query.run_batch`) sketches the batch once
+        (shards share the hash family), probes every shard's levels with
+        the same sketches, sweeps them, and unions per query in the global
+        id space.
 
         Execution comes in as ``options=QueryOptions(...)`` whose ``plan``
         is resolved once for the whole fan-out (every shard runs the same
         resolved stages; ``plan="device"`` probes each frozen shard's
         resident arena).  The pre-redesign ``sketches``/``backend``/
-        ``probe_backend``/``fanout`` keywords still work behind a
-        ``DeprecationWarning``.
+        ``fanout`` keywords still work behind a ``DeprecationWarning``.
 
         ``QueryOptions.fanout="threaded"`` (default) overlaps the
-        per-shard *probe* stage (:func:`repro.core.query.batch_probe`)
-        with a thread pool — NumPy releases the GIL inside
-        searchsorted/gather and mmap-backed shards overlap page-ins — and
-        then runs the GIL-bound plane-sweep stage serially (threading it
-        just convoys on the GIL); ``"serial"`` keeps the fully sequential
-        loop.  Results are merged in shard order either way, so the two
-        are block-identical.  ``probe_backend`` picks each shard's probe
-        path, and ``sketches`` short-circuits sketching when the caller
-        already holds the batch's sketch coordinates (shards share the
-        hash family, so they are computed once regardless).
-        ``stage_times`` accumulates per-stage wall seconds under
-        ``"sketch"``/``"probe"``/``"sweep"`` and the sweep's children
-        (:mod:`repro.core.spans`) when given; the pool threads of the
-        probe fan-out write into no shared dict.
+        per-shard *probe* stage with a thread pool — NumPy releases the
+        GIL inside searchsorted/gather and mmap-backed shards overlap
+        page-ins — and then runs the GIL-bound plane-sweep stage serially
+        (threading it just convoys on the GIL); ``"serial"`` keeps the
+        fully sequential loop.  Results are merged in shard order either
+        way, so the two are block-identical.  ``stage_times`` accumulates
+        per-stage wall seconds under ``"sketch"``/``"probe"``/``"sweep"``
+        and their children (:mod:`repro.core.spans`) when given; the pool
+        threads of the probe fan-out write into no shared dict.
 
         **Degraded mode**: with ``failures`` set to a caller-owned list,
         a shard whose probe keeps raising after ``shard_retries`` bounded
@@ -268,59 +260,38 @@ class ShardedAlignmentIndex:
         """
         opts = coerce_query_options(
             options, "ShardedAlignmentIndex.batch_query", sketches=sketches,
-            backend=backend, probe_backend=probe_backend, fanout=fanout)
-        xp = resolve_plan(opts)
-        if not texts:
-            return []
-        with span(stage_times, "sketch"):
-            sk = opts.sketches
-            if sk is None:
-                sk = self.scheme.sketch_batch(texts,
-                                              backend=xp.sketch_backend)
-            inverse = self._inverse_doc_map()
-            B = len(texts)
-            m = max(1, math.ceil(self.scheme.k * theta))
+            backend=backend, fanout=fanout)
+        inverse = self._inverse_doc_map()
 
-        def probe_shard(s_shard):
-            # runs on the fan-out pool: writes into no shared stage dict
-            s, shard = s_shard
+        def probe_shard(probe, s: int, shard, times: dict | None) -> list:
             attempts = 1 + (shard_retries if failures is not None else 0)
             delay = retry_backoff_s
             for attempt in range(attempts):
                 try:
                     fault_checkpoint(f"sharded.probe.s{s}")
-                    return batch_probe(shard, sk,
-                                       probe_backend=xp.probe_backend)
+                    return probe(shard, times=times,
+                                 ids=lambda t: inverse[(s, t)])
                 except Exception:
                     if attempt + 1 >= attempts:
                         if failures is None:
                             raise
                         failures.append(s)
-                        return None
+                        return []           # the union misses its docs
                     time.sleep(delay)
                     delay *= 2
 
-        with span(stage_times, "probe"):
-            if xp.fanout == "threaded" and self.n_shards > 1:
-                gathered = list(self._fanout_pool().map(
-                    probe_shard, enumerate(self.shards)))
+        def fan_out(probe, how: str) -> list:
+            if how == "threaded" and self.n_shards > 1:
+                parts = self._fanout_pool().map(
+                    lambda s: probe_shard(probe, s, self.shards[s], None),
+                    range(self.n_shards))
             else:
-                gathered = [probe_shard(s) for s in enumerate(self.shards)]
-        with span(stage_times, "sweep"):
-            # a failed (skipped) shard contributes an empty result per
-            # query; the shards sweep on this thread, one after another
-            shard_results = [
-                _sweep_gathered(g, B, m, xp.sweep, stage_times)
-                if g is not None else [[] for _ in texts]
-                for g in gathered]
-            per_q: list[list[Alignment]] = [[] for _ in texts]
-            for s, res in enumerate(shard_results):
-                for qi, als in enumerate(res):
-                    per_q[qi].extend(
-                        Alignment(text_id=inverse[(s, al.text_id)],
-                                  blocks=al.blocks, ncoords=al.ncoords)
-                        for al in als)
-            return [sorted(r, key=lambda a: a.text_id) for r in per_q]
+                parts = [probe_shard(probe, s, shard, stage_times)
+                         for s, shard in enumerate(self.shards)]
+            return [level for part in parts for level in part]
+
+        return run_batch(self, texts, theta, opts, stage_times,
+                         fan_out=fan_out)
 
     def freeze(self) -> "ShardedAlignmentIndex":
         """Freeze every shard into the CSR serving layout (idempotent).

@@ -15,11 +15,12 @@ The span names are the ``stage_times`` keys the engine fills (and
 ``sketch``         sketching the batch
 ``probe``          encoding the probe keys, the probe, the window gather
 ``probe.device``   the resident-arena probe call through its read-back
-``probe.gather``   the window gather: on the fused path, the text runs
-                   of the probe's extents, read from the run table
+``probe.gather``   the window gather: on a resident level (the device
+                   plan), the text runs of the probe's extents, read
+                   from the run table
 ``sweep``          grouping, every sweep, and building the alignments
 ``sweep.group``    the (query, text) grouping and the >= m prefilter;
-                   on the fused path over text runs, then the kept
+                   on a resident level over text runs, then the kept
                    groups' rows
 ``sweep.device``   per size bucket: the device gather and sweep through
                    the read-back of the coverage grids

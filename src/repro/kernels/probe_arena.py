@@ -1,7 +1,9 @@
 """Device-side probe of the fused CSR arena.
 
 One call binary-searches every probe key of a batch against the arena's
-sorted key array (``repro.core.frozen.ProbeArena``).  Keys are uint64 on
+sorted key array (``repro.core.frozen.ProbeArena``), resident on the
+device (``repro.core.device_plan`` builds its jitted probe from
+:func:`_arena_search`).  Keys are uint64 on
 the host but TPU VPUs have no 64-bit integer lanes, so arena and probe
 keys are split into (hi, lo) uint32 halves and compared lexicographically;
 the coordinate tag of the arena's "coord" mode rides along as a third
@@ -63,22 +65,3 @@ def _arena_search(khi, klo, ktag, qhi, qlo, qtag):
 def _split_u64(a: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     a = np.ascontiguousarray(a, dtype=np.uint64)
     return ((a >> np.uint64(32)).astype(np.uint32), a.astype(np.uint32))
-
-
-def arena_search(keys: np.ndarray, tags: np.ndarray, qkeys: np.ndarray,
-                 qtags: np.ndarray) -> np.ndarray:
-    """Leftmost slot with (key, tag) >= (qkey, qtag) per probe, int32 (P,).
-
-    keys (n,) u64 sorted lexicographically with tags (n,) u32 as the tie
-    break; qkeys (P,) u64, qtags (P,) u32.  Uploads the arena on every
-    call; ``repro.core.device_plan`` keeps it resident instead.
-    """
-    if len(keys) == 0:
-        return np.zeros(len(qkeys), np.int32)
-    khi, klo = _split_u64(keys)
-    qhi, qlo = _split_u64(qkeys)
-    return np.asarray(_arena_search(
-        jnp.asarray(khi), jnp.asarray(klo),
-        jnp.asarray(tags, dtype=jnp.uint32),
-        jnp.asarray(qhi), jnp.asarray(qlo),
-        jnp.asarray(qtags, dtype=jnp.uint32)))

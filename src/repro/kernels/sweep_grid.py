@@ -24,8 +24,8 @@ coverage and only duplicating existing compressed coordinates.
 The kernel returns the hot mask (coverage ≥ m, zero-width x-stripes masked
 cold) plus ``xs``/``ys`` — a few KB per batch — and the host extracts
 maximal runs with the same code the NumPy path uses
-(``repro.core.query._extract_runs``), which is what makes
-``sweep="device"`` block-for-block identical to ``sweep="grouped"``.
+(``repro.core.query._extract_runs``), which is what makes the device
+plan's small-group sweep block-for-block identical to the cpu plan's.
 """
 
 from __future__ import annotations
@@ -165,8 +165,10 @@ def sweep_grid(rects, sizes, *, m: int, interpret: bool | None = None):
 
 def sweep_small_batch_device(arr: np.ndarray, sizes: np.ndarray, m: int
                              ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Host-array wrapper: (G, S, 4) rect rows -> (hot bool (G, NX-1, NX-1),
-    xs (G, NX), ys (G, NX)) as NumPy, ready for ``_extract_runs``."""
+    """(G, S, 4) rect rows — host rows, uploaded here, or rows already
+    on the device — -> (hot bool (G, NX-1, NX-1), xs (G, NX), ys (G, NX))
+    as NumPy, ready for ``_extract_runs``.  The device small-group sweep
+    of ``repro.core.query.sweep_groups``."""
     hot, xs, ys = sweep_grid(jnp.asarray(arr, jnp.int32),
                              jnp.asarray(sizes, jnp.int32), m=int(m))
     NX = xs.shape[1]

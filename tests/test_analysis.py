@@ -246,7 +246,7 @@ API_FIXTURE = '''
 from repro.core.index import AlignmentIndex          # RPR403
 
 def old_style(aligner, qs):
-    res = aligner.find_batch(qs, 0.5, probe_backend="percoord")  # RPR401
+    res = aligner.find_batch(qs, 0.5, backend="exact")  # RPR401
     raw = aligner.find(qs[0], 0.5, legacy_tuples=True)           # RPR402
     idx = AlignmentIndex(scheme=None)                # RPR403
     return res, raw, idx
@@ -273,16 +273,16 @@ def test_api_rules_flag_each_deprecated_surface(tmp_path):
 
 
 def test_rpr404_method_calls_defer_overlap_to_rpr401(tmp_path):
-    # on a *method* call RPR401 owns probe_backend/sweep/sketches; RPR404
+    # on a *method* call RPR401 owns backend/fanout/sketches; RPR404
     # adds only the spellings RPR401 cannot see (sketch_backend, and any
     # stage kwarg on a bare-function call) so one call site never earns
     # two findings for the same kwarg
     _write(tmp_path, "pkg/mixed.py", '''
 def f(aligner, idx, qs, sk):
     from repro.core import batch_query
-    aligner.find_batch(qs, 0.5, sweep="loop")              # RPR401 only
+    aligner.find_batch(qs, 0.5, sketches=sk)               # RPR401 only
     aligner.find_batch(qs, 0.5, sketch_backend="exact")    # RPR404 only
-    batch_query(idx, qs, 0.5, probe_backend="numpy", sweep="loop")  # RPR404
+    batch_query(idx, qs, 0.5, sketch_backend="exact", sketches=sk)  # RPR404
 ''')
     report = run_analysis(["pkg"], rules=["RPR4"], root=tmp_path)
     assert _rules(report) == ["RPR401", "RPR404"]

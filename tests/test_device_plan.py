@@ -5,11 +5,14 @@ to ``plan="cpu"`` on every scheme (the kernels run in interpret mode on
 CPU CI), the ProbeArena goes device-resident at most once per store
 generation (and re-uploads exactly once when compaction/promotion swaps
 the generation), the mutable live delta level transparently keeps the
-host probe, ``plan="auto"`` downgrades silently when no accelerator backs
-jax, and the legacy per-stage kwargs still work one release behind a
-``DeprecationWarning`` that names the removal release.
+host probe, every store kind runs the one batch pipeline (the frozen base
+of a live store and every shard of a sharded store take the run path),
+``plan="auto"`` downgrades silently when no accelerator backs jax, and
+the legacy kwargs still work one release behind a ``DeprecationWarning``
+that names the removal release.
 """
 
+import importlib
 import warnings
 
 import numpy as np
@@ -17,11 +20,14 @@ import pytest
 
 from repro.api import Aligner
 from repro.core import (IndexBuilder, LiveIndex, MultisetScheme,
-                        QueryOptions, WeightedScheme, WeightFn, batch_query,
-                        make_scheme, resolve_plan, save_index)
+                        QueryOptions, ShardedAlignmentIndex, WeightedScheme,
+                        WeightFn, batch_query, make_scheme, resolve_plan,
+                        save_index)
 from repro.core import device_plan as dp
 from repro.core.device_plan import (device_arena, reset_transfer_stats,
                                     resident_probe, transfer_stats)
+
+Q = importlib.import_module("repro.core.query")
 
 SCHEMES = {
     "multiset": lambda docs: MultisetScheme(seed=13, k=8),
@@ -85,14 +91,14 @@ def test_resident_probe_matches_host_probe(kind):
     arena = frozen.arena()
     sketches = frozen.scheme.sketch_batch(_queries(rng, docs))
     pk, co, va = arena.encode_batch(sketches)
-    host_s, host_e = arena.probe(pk, co, va, backend="numpy")
+    host_s, host_e = arena.probe(pk, co, va)
     dev_s, dev_e = resident_probe(frozen, pk, co, va)
     assert np.array_equal(dev_s, host_s)
     assert np.array_equal(dev_e, host_e)
 
 
 def test_device_plan_on_mutable_builder_falls_back_to_host_probe():
-    # fused pipeline needs a frozen index; a dict-table builder under
+    # the resident probe needs a frozen index; a dict-table builder under
     # plan="device" still answers (host per-coordinate probe, device sweep)
     rng = np.random.default_rng(2)
     docs = _corpus(rng)
@@ -175,12 +181,8 @@ def test_oversized_arena_caches_host_fallback(monkeypatch):
     # pretend the CSR extent overflows the probe's int32 offsets
     monkeypatch.setattr(dp, "_I32_MAX", -1)
     reset_transfer_stats()
-    for probe_backend in (None, "device"):       # fused and pinned probe
-        opts = QueryOptions(plan="device", probe_backend=probe_backend,
-                            sweep=None if probe_backend is None
-                            else "grouped")
-        with pytest.raises(dp.DeviceArenaError, match="int32"):
-            batch_query(frozen, qs, 0.5, options=opts)
+    with pytest.raises(dp.DeviceArenaError, match="int32"):
+        batch_query(frozen, qs, 0.5, options=QueryOptions(plan="device"))
     st = transfer_stats()
     assert st["arena_uploads"] == 0 and st["batches"] == 0
     assert st["h2d_bytes"] == 0 and st["d2h_bytes"] == 0
@@ -224,10 +226,10 @@ def test_live_delta_serves_device_plan_via_host_fallback(tmp_path):
 def test_auto_plan_downgrades_without_accelerator():
     xp = resolve_plan(QueryOptions(plan="auto"),
                       capabilities={"device": False})
-    assert xp.name == "cpu" and not xp.fused
+    assert xp.name == "cpu" and not xp.device
     xp = resolve_plan(QueryOptions(plan="auto"),
                       capabilities={"device": True})
-    assert xp.name == "device" and xp.fused
+    assert xp.name == "device" and xp.device
     # no capability override: follows the real backend probe, silently
     assert resolve_plan(QueryOptions(plan="auto")).name in ("cpu", "device")
 
@@ -235,20 +237,45 @@ def test_auto_plan_downgrades_without_accelerator():
 def test_resolved_device_plan_keeps_exact_sketching():
     xp = resolve_plan(QueryOptions(plan="device"))
     assert xp.sketch_backend == "exact"           # bit parity by default
-    assert xp.probe_backend == "device" and xp.sweep == "device"
+    assert xp.device and xp.fanout == "threaded"
 
 
 def test_stage_pins_override_plan_defaults():
-    xp = resolve_plan(QueryOptions(plan="device", sweep="grouped"))
-    assert xp.sweep == "grouped" and not xp.fused
-    assert xp.probe_backend == "device"
+    # the plan alone chooses probe and sweep: the old probe_backend and
+    # sweep pins are unknown options on the wire, in QueryOptions and as
+    # keywords of every query entry point
+    for name in ("probe_backend", "sweep"):
+        with pytest.raises(ValueError, match="unknown query options"):
+            QueryOptions.from_dict({"plan": "device", name: "device"})
+        with pytest.raises(TypeError):
+            QueryOptions(plan="device", **{name: "device"})
+    rng = np.random.default_rng(11)
+    docs = _corpus(rng)
+    frozen = _frozen("multiset", docs)
+    live = LiveIndex(frozen=frozen, delta=IndexBuilder(
+        scheme=frozen.scheme), doc_map=list(range(len(docs))))
+    sharded = ShardedAlignmentIndex(scheme=frozen.scheme, n_shards=2)
+    sharded.build(docs)
+    al = Aligner.build(docs, similarity="multiset", k=8)
+    calls = [lambda **kw: batch_query(frozen, docs[:1], 0.5, **kw),
+             lambda **kw: live.batch_query(docs[:1], 0.5, **kw),
+             lambda **kw: sharded.batch_query(docs[:1], 0.5, **kw),
+             lambda **kw: al.find_batch(docs[:1], 0.5, **kw)]
+    for call in calls:
+        for name in ("probe_backend", "sweep"):
+            with pytest.raises(TypeError, match=name):
+                call(**{name: "device"})
+    # the plan's own stages resolve from the plan and the two pins left
+    xp = resolve_plan(QueryOptions(plan="device", fanout="serial"))
+    assert xp.device and xp.fanout == "serial"
+    assert xp.sketch_backend == "exact"
 
 
 def test_unknown_plan_and_invalid_pin_are_errors():
     with pytest.raises(ValueError, match="unknown execution plan"):
         resolve_plan(QueryOptions(plan="gpu"))
     with pytest.raises(TypeError, match="cannot execute"):
-        resolve_plan(QueryOptions(plan="cpu", probe_backend="device"))
+        resolve_plan(QueryOptions(plan="cpu", fanout="process"))
 
 
 # --------------------------------------------------------------------------
@@ -261,11 +288,10 @@ def test_legacy_kwargs_warn_name_release_and_round_trip():
     frozen = _frozen("multiset", docs)
     qs = _queries(rng, docs)
     new = batch_query(frozen, qs, 0.5,
-                      options=QueryOptions(probe_backend="percoord",
-                                           sweep="loop"))
+                      options=QueryOptions(sketch_backend="exact"))
     with pytest.warns(DeprecationWarning, match=r"removed in release 0\.3"):
         old = batch_query(frozen, qs, 0.5,                      # repro: allow[RPR404]
-                          probe_backend="percoord", sweep="loop")
+                          sketch_backend="exact")
     assert _batch_blocks(old) == _batch_blocks(new)
 
 
@@ -286,7 +312,7 @@ def test_mixing_options_and_legacy_kwargs_is_an_error():
     frozen = _frozen("multiset", docs)
     with pytest.raises(TypeError, match="both"):
         batch_query(frozen, [docs[0][:20]], 0.5,    # repro: allow[RPR404]
-                    options=QueryOptions(plan="cpu"), sweep="loop")
+                    options=QueryOptions(plan="cpu"), sketch_backend="exact")
 
 
 # --------------------------------------------------------------------------
@@ -320,13 +346,14 @@ def test_fused_path_gathers_in_int32(monkeypatch):
     # the per-window arrays of a batch are half the bytes of int64 ones:
     # past 32 MiB the allocator maps each afresh on every batch
     seen = {}
-    real = dp._fused_sweep
+    real = Q.sweep_groups
 
-    def spy(arena, da, rows, cids, *rest):
-        seen.update(row=rows.dtype, cid=cids.dtype)
-        return real(arena, da, rows, cids, *rest)
+    def spy(g, *rest):
+        if isinstance(g.source, dp.DeviceArena):   # the run grouping's
+            seen.update(row=g.rows.dtype, cid=g.cids.dtype)
+        return real(g, *rest)
 
-    monkeypatch.setattr(dp, "_fused_sweep", spy)
+    monkeypatch.setattr(Q, "sweep_groups", spy)
     rng = np.random.default_rng(0)
     docs = _corpus(rng)
     frozen = _frozen("multiset", docs)
@@ -373,3 +400,108 @@ def test_device_plan_counts_probes_and_host_swept_groups(live, tmp_path):
     assert st["sweep_launches"] >= 1
     assert _batch_blocks(got) == _batch_blocks(
         batch_query(frozen, qs, 0.5, options=QueryOptions(plan="cpu")))
+
+
+# --------------------------------------------------------------------------
+# one pipeline: live and sharded stores take the run path as well
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kind", ["multiset", "tfidf"])
+def test_live_store_with_delta_takes_the_run_path(tmp_path, kind):
+    rng = np.random.default_rng(12)
+    base = _corpus(rng, n_docs=8)
+    scheme = SCHEMES[kind](base)
+    save_index(IndexBuilder(scheme=scheme).build(base).freeze(),
+               tmp_path / "idx")
+    live = LiveIndex.open(tmp_path / "idx")
+    delta = [base[3].copy(), rng.integers(0, 30, 50).astype(np.int64)]
+    for t in delta:
+        live.add_text(t)
+    assert live.delta.num_texts == len(delta)
+    qs = _queries(rng, base) + [delta[0][:30]]
+    cpu = live.batch_query(qs, 0.5, options=QueryOptions(plan="cpu"))
+    reset_transfer_stats()
+    dev = live.batch_query(qs, 0.5, options=QueryOptions(plan="device"))
+    st = transfer_stats()
+    assert st["batches"] == 1                     # the frozen level alone
+    assert st["probe_text_runs"] > 0              # grouped over runs
+    assert _batch_blocks(dev) == _batch_blocks(cpu)
+    assert [[a.ncoords for a in r] for r in dev] == \
+        [[a.ncoords for a in r] for r in cpu]
+    assert any(tid >= len(base) for r in dev for tid, _ in _blocks(r))
+    oracle = IndexBuilder(scheme=scheme).build(base + delta)
+    assert _batch_blocks(dev) == _batch_blocks(batch_query(oracle, qs, 0.5))
+
+
+@pytest.mark.parametrize("kind", ["multiset", "tfidf"])
+def test_two_shard_store_takes_the_run_path_threaded(kind):
+    rng = np.random.default_rng(13)
+    docs = _corpus(rng, n_docs=8)
+    scheme = SCHEMES[kind](docs)
+    sharded = ShardedAlignmentIndex(scheme=scheme, n_shards=2)
+    sharded.build(docs).freeze()
+    qs = _queries(rng, docs)
+    cpu = sharded.batch_query(
+        qs, 0.5, options=QueryOptions(plan="cpu", fanout="threaded"))
+    reset_transfer_stats()
+    dev = sharded.batch_query(
+        qs, 0.5, options=QueryOptions(plan="device", fanout="threaded"))
+    # counted by the sweep, on the calling thread: each shard's frozen
+    # level was grouped over text runs
+    assert transfer_stats()["probe_text_runs"] > 0
+    assert all(getattr(shard, "_device_arena", None) is not None
+               for shard in sharded.shards)
+    assert _batch_blocks(dev) == _batch_blocks(cpu)
+    assert [[a.ncoords for a in r] for r in dev] == \
+        [[a.ncoords for a in r] for r in cpu]
+    oracle = IndexBuilder(scheme=scheme).build(docs)
+    assert _batch_blocks(dev) == _batch_blocks(batch_query(oracle, qs, 0.5))
+
+
+def _store(kind, docs, tmp_path):
+    scheme = MultisetScheme(seed=13, k=8)
+    if kind == "sharded":
+        return ShardedAlignmentIndex(scheme=scheme, n_shards=2).build(
+            docs).freeze()
+    if kind in ("builder", "frozen"):
+        builder = IndexBuilder(scheme=scheme).build(docs)
+        return builder if kind == "builder" else builder.freeze()
+    save_index(IndexBuilder(scheme=scheme).build(docs[:-2]).freeze(),
+               tmp_path / "idx")
+    live = LiveIndex.open(tmp_path / "idx")
+    for t in docs[-2:]:
+        live.add_text(t)
+    return live
+
+
+@pytest.mark.parametrize("plan", ["cpu", "device"])
+@pytest.mark.parametrize("kind", ["frozen", "builder", "live", "sharded"])
+def test_every_store_kind_reaches_the_one_sweep(monkeypatch, tmp_path,
+                                                kind, plan):
+    rng = np.random.default_rng(14)
+    docs = _corpus(rng, n_docs=8)
+    store = _store(kind, docs, tmp_path)
+    qs = _queries(rng, docs) + [docs[-1][:30]]
+    want = _batch_blocks(batch_query(
+        IndexBuilder(scheme=MultisetScheme(seed=13, k=8)).build(docs),
+        qs, 0.5))
+    sources = []
+    real = Q.sweep_groups
+
+    def spy(g, *rest):
+        sources.append(type(g.source).__name__)
+        return real(g, *rest)
+
+    monkeypatch.setattr(Q, "sweep_groups", spy)
+    opts = QueryOptions(plan=plan)
+    got = (batch_query(store, qs, 0.5, options=opts)
+           if kind in ("frozen", "builder")
+           else store.batch_query(qs, 0.5, options=opts))
+    assert _batch_blocks(got) == want
+    # one sweep per level: a live store's frozen base and its delta, each
+    # shard of a sharded store; a frozen level's rows stay on the device
+    # arena under the device plan
+    frozen = "DeviceArena" if plan == "device" else "HostRows"
+    assert sources == {"frozen": [frozen], "builder": ["HostRows"],
+                       "live": [frozen, "HostRows"],
+                       "sharded": [frozen, frozen]}[kind]

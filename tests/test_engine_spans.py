@@ -137,10 +137,14 @@ def test_find_batch_spans_nest_inside_their_parents(tmp_path, corpus, kind,
     spent = sum(times[n] for n in PARENTS) + times["results"]
     assert spent <= wall
     assert wall - spent < 0.05 + 0.1 * wall
-    fused = plan == "device" and kind == "frozen"
-    assert ("probe.device" in times) == fused
-    assert ("probe.gather" in times) == fused
-    assert ("sweep.large.read" in times) == fused
+    # under the device plan every frozen level (a frozen store, a live
+    # store's base, each shard) is probed on its resident arena and
+    # grouped over text runs; the sharded fan-out's pool threads time no
+    # span, so its probe calls go untimed
+    resident = plan == "device" and kind != "builder"
+    assert ("probe.device" in times) == (resident and kind != "sharded")
+    assert ("probe.gather" in times) == resident
+    assert ("sweep.large.read" in times) == resident
     assert ("sweep.device" in times) == (plan == "device")
 
 

@@ -109,8 +109,8 @@ def test_live_matches_scratch_build(tmp_path, similarity, mmap):
     assert _batch_blocks(again.batch_query(qs, 0.5)) == expected2
 
 
-@pytest.mark.parametrize("probe_backend", ["numpy", "percoord"])
-def test_live_probe_backends_agree(tmp_path, probe_backend):
+@pytest.mark.parametrize("plan", ["cpu", "device"])
+def test_live_probe_backends_agree(tmp_path, plan):
     rng = np.random.default_rng(2)
     base = _corpus(rng)
     scheme = _scheme("multiset", base)
@@ -123,7 +123,7 @@ def test_live_probe_backends_agree(tmp_path, probe_backend):
     oracle = IndexBuilder(scheme=scheme).build(base + delta)
     assert _batch_blocks(
         live.batch_query(qs, 0.5,
-                         options=QueryOptions(probe_backend=probe_backend))) == \
+                         options=QueryOptions(plan=plan))) == \
         _batch_blocks(batch_query(oracle, qs, 0.5))
 
 

@@ -1,5 +1,5 @@
 """Fused probe arena: parity with the per-table probes on both re-keying
-schemes, Pallas-vs-NumPy backend equality, the grouped small-sweep
+schemes, host-vs-resident probe equality, the grouped small-sweep
 dispatcher, threaded-vs-serial sharded fan-out, and arena persistence."""
 
 import json
@@ -69,7 +69,7 @@ def test_arena_mode_selection_and_layout(kind):
 
 
 @pytest.mark.parametrize("kind", list(SCHEMES))
-@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+@pytest.mark.parametrize("backend", ["numpy", "device"])
 def test_arena_probe_matches_per_table_probe(kind, backend):
     rng = np.random.default_rng(1)
     docs = _corpus(rng)
@@ -78,7 +78,11 @@ def test_arena_probe_matches_per_table_probe(kind, backend):
     k = arena.k
     sketches = frozen.scheme.sketch_batch(_queries(rng, docs))
     pkeys, coords, valid = arena.encode_batch(sketches)
-    starts, ends = arena.probe(pkeys, coords, valid, backend=backend)
+    if backend == "device":          # the device plan's resident arena
+        from repro.core.device_plan import resident_probe
+        starts, ends = resident_probe(frozen, pkeys, coords, valid)
+    else:
+        starts, ends = arena.probe(pkeys, coords, valid)
     for b, sk in enumerate(sketches):
         for i in range(k):
             table = frozen.tables[i]
@@ -168,13 +172,11 @@ def test_batch_query_backends_equal_looped_query(kind, theta):
     builder = IndexBuilder(scheme=SCHEMES[kind]()).build(docs)
     frozen = builder.freeze()
     looped = [_blocks(query(builder, q, theta)) for q in qs]
-    for probe_backend in ("numpy", "pallas", "percoord"):
-        for sweep in ("grouped", "loop"):
+    for plan in ("cpu", "device"):
+        for index in (frozen, builder):
             got = [_blocks(r) for r in batch_query(
-                frozen, qs, theta,
-                options=QueryOptions(probe_backend=probe_backend,
-                                     sweep=sweep))]
-            assert got == looped, (probe_backend, sweep)
+                index, qs, theta, options=QueryOptions(plan=plan))]
+            assert got == looped, (plan, index.is_frozen)
 
 
 def test_batch_query_empty_batch_and_all_miss():

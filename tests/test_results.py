@@ -76,14 +76,15 @@ def test_query_options_batch_key_excludes_sketches():
     a = QueryOptions(sketches=[[1, 2]])
     b = QueryOptions(sketches=None)
     assert a.batch_key() == b.batch_key()
-    assert QueryOptions(sweep="loop").batch_key() != b.batch_key()
+    assert QueryOptions(sketch_backend="pallas").batch_key() != \
+        b.batch_key()
 
 
 def test_query_options_dict_roundtrip_rejects_unknown():
-    opts = QueryOptions(probe_backend="percoord", sweep="loop")
+    opts = QueryOptions(sketch_backend="pallas", fanout="serial")
     assert QueryOptions.from_dict(opts.to_dict()) == opts
     with pytest.raises(ValueError, match="unknown"):
-        QueryOptions.from_dict({"probe_backnd": "numpy"})
+        QueryOptions.from_dict({"sketch_backnd": "exact"})
     with pytest.raises(ValueError):
         QueryOptions.from_dict({"sketches": [[1]]})
 
@@ -91,11 +92,11 @@ def test_query_options_dict_roundtrip_rejects_unknown():
 def test_legacy_kwargs_warn_and_coerce():
     aligner, docs = _mk()
     q = [int(t) for t in docs[0][:60]]
-    with pytest.warns(DeprecationWarning, match="probe_backend"):
-        res = aligner.find_batch(  # repro: allow[RPR401] (tests the shim)
-            [q], 0.5, probe_backend="percoord")
+    with pytest.warns(DeprecationWarning, match="sketch_backend"):
+        res = aligner.find_batch(  # repro: allow[RPR404] (tests the shim)
+            [q], 0.5, sketch_backend="exact")
     assert res == aligner.find_batch(
-        [q], 0.5, options=QueryOptions(probe_backend="percoord"))
+        [q], 0.5, options=QueryOptions(sketch_backend="exact"))
     # `backend` renames to sketch_backend, and the warning says so
     with pytest.warns(DeprecationWarning, match="sketch_backend"):
         coerced = coerce_query_options(None, "find_batch", backend="exact")
@@ -105,7 +106,7 @@ def test_legacy_kwargs_warn_and_coerce():
 def test_mixing_options_and_legacy_kwargs_is_an_error():
     with pytest.raises(TypeError, match="both"):
         coerce_query_options(QueryOptions(), "find_batch",
-                             probe_backend="numpy")
+                             sketch_backend="exact")
 
 
 def test_alignment_index_reexport_removed():
@@ -146,7 +147,8 @@ def test_weighted_sketch_batch_empty_text_falls_back():
 def test_live_empty_delta_skips_delta_probe(tmp_path, monkeypatch):
     """A freshly opened live store has zero delta tables; its batch
     queries must probe the frozen level only."""
-    import repro.core.live as live_mod
+    import importlib
+    Q = importlib.import_module("repro.core.query")
     rng = np.random.default_rng(4)
     docs = [rng.integers(0, 1 << 40, size=100) for _ in range(12)]
     store = str(tmp_path / "idx")
@@ -155,13 +157,13 @@ def test_live_empty_delta_skips_delta_probe(tmp_path, monkeypatch):
     aligner = Aligner.load(store, live=True)
 
     calls = []
-    orig = live_mod._batch_probe
+    orig = Q._probe_level
 
-    def counting(index, sketches, **kw):
-        calls.append(index)
-        return orig(index, sketches, **kw)
+    def counting(level, *args):
+        calls.append(level)
+        return orig(level, *args)
 
-    monkeypatch.setattr(live_mod, "_batch_probe", counting)
+    monkeypatch.setattr(Q, "_probe_level", counting)
     queries = [[int(t) for t in docs[0][:60]]]
     res = aligner.find_batch(queries, 0.5)
     assert res[0], "self-query must hit"

@@ -1,12 +1,13 @@
 """The fused device path groups probe hits by text runs.
 
 Inside each arena slot's extent, the windows of one text lie in runs.
-The fused path (``device_plan.fused_batch_query``) counts each (query,
-text) group's distinct coordinates over those runs and expands the rows
-of the kept groups alone.  The contract under test: it keeps exactly the
-rows the cpu plan's row-level grouping (``query._group_bounds``) keeps,
-in the same order, and answers block for block as ``plan="cpu"`` does,
-on coord and packed arenas, compacted live stores and arenas whose
+The device plan's run grouping (``device_plan._kept_groups``) counts each
+(query, text) group's distinct coordinates over those runs and expands
+the rows of the kept groups alone.  The contract under test: it keeps
+exactly the rows the cpu plan's row-level grouping (``query._group_bounds``,
+``query._row_groups``) keeps, in the same order and as the same
+``KeptGroups`` record, and answers block for block as ``plan="cpu"``
+does, on coord and packed arenas, compacted live stores and arenas whose
 extents hold one text in several runs.
 """
 
@@ -17,7 +18,8 @@ from repro.core import (IndexBuilder, LiveIndex, MultisetScheme,
                         QueryOptions, batch_query, make_scheme, save_index)
 from repro.core import device_plan as dp
 from repro.core.frozen import MODE_COORD, MODE_PACKED, _concat_ranges
-from repro.core.query import _SMALL_GROUP_MAX, _group_bounds
+from repro.core.query import _SMALL_GROUP_MAX, _group_bounds, batch_probe
+from repro.core.query import _row_groups as row_grouping
 
 K = 8
 SCHEMES = {
@@ -54,7 +56,7 @@ def _blocks(res):
 def _extents(index, qs):
     arena = index.arena()
     pk, co, va = arena.encode_batch(index.scheme.sketch_batch(qs))
-    return arena.probe(pk, co, va, backend="numpy")
+    return arena.probe(pk, co, va)
 
 
 def _row_groups(index, starts, ends, m):
@@ -74,10 +76,19 @@ def _row_groups(index, starts, ends, m):
     return rows[at], c[at], sizes, q[head], tid[head], distinct[kept]
 
 
-def _run_groups(index, starts, ends, m):
+FIELDS = ("rows", "cids", "sizes", "qids", "tids", "distinct")
+
+
+def _kept(index, starts, ends, m):
     da = dp.device_arena(index)
     probe_r, run_r = dp._probe_runs(da.run_start, starts, ends)
-    return dp._kept_groups(da, probe_r, run_r, index.arena().k, m)
+    return dp._kept_groups(da, probe_r, run_r, index.arena().k, m,
+                           hits=int((ends - starts).sum()))
+
+
+def _run_groups(index, starts, ends, m):
+    g = _kept(index, starts, ends, m)
+    return tuple(getattr(g, name) for name in FIELDS)
 
 
 def _runs_in(index, starts, ends):
@@ -92,11 +103,21 @@ def _assert_same_groups(index, qs, theta):
     m = int(np.ceil(K * theta))
     starts, ends = _extents(index, qs)
     want = _row_groups(index, starts, ends, m)
-    got = _run_groups(index, starts, ends, m)
-    for name, w, g in zip(("rows", "cids", "sizes", "qids", "tids",
-                           "distinct"), want, got):
+    kept = _kept(index, starts, ends, m)
+    got = tuple(getattr(kept, name) for name in FIELDS)
+    for name, w, g in zip(FIELDS, want, got):
         assert np.array_equal(g, w), name
     assert got[0].dtype == got[1].dtype == np.int32
+    # the engine's row grouping returns the same record over the rows it
+    # gathered on the host: the same rectangles, coordinates and groups
+    rows = row_grouping(*batch_probe(index, index.scheme.sketch_batch(qs)),
+                        m)
+    assert np.array_equal(rows.source.read(rows.rows),
+                          kept.source.read(kept.rows))
+    for name in FIELDS[1:]:
+        assert np.array_equal(getattr(rows, name), getattr(kept, name)), \
+            name
+    assert rows.hits == kept.hits
     return got
 
 

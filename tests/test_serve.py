@@ -164,7 +164,8 @@ def test_batcher_splits_incompatible_options():
         futs = [batcher.submit_query(q, 0.5),
                 batcher.submit_query(q, 0.8),
                 batcher.submit_query(q, 0.5,
-                                     options=QueryOptions(sweep="loop"))]
+                                     options=QueryOptions(
+                                         sketch_backend="exact"))]
         await asyncio.gather(*futs)
         await batcher.close()
 
